@@ -10,7 +10,7 @@ import numpy as np
 
 from benchmarks.common import row, time_fn
 from repro.kernels import ops, ref
-from repro.launch.mesh import PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 
 def run():
@@ -27,7 +27,8 @@ def run():
     fl = 2.0 * m * k * n
     rows.append(row("kernel_fused_dense_fp32", t * 1e6,
                     f"{fl / t / 1e9:.1f} GFLOP/s cpu; "
-                    f"tpu-roofline {fl / PEAK_FLOPS_BF16 * 1e6:.2f} us"))
+                    f"v5e-roofline "
+                    f"{fl / chip_peaks(V5E).flops_bf16 * 1e6:.2f} us"))
 
     unfused = jax.jit(lambda x_: jnp.maximum(x_ @ w + b, 0.0))
     t2, _ = time_fn(unfused, x)

@@ -503,9 +503,8 @@ def _eff_attention(op, n_rows, n_hits):
 def _bind_fused_dense(op, ctx: BindContext):
     """Variant selection / block tuning for the fused_dense template
     (cached winner > heuristic) — see passes/kernel_opt.py."""
-    from repro.core.passes.kernel_opt import (FLATTEN_DIM, FLATTEN_ROWS,
-                                              _FUSED_DENSE_KNOBS,
-                                              _pick_block,
+    from repro.core.passes.kernel_opt import (_FUSED_DENSE_KNOBS,
+                                              fused_dense_default,
                                               fused_dense_dtype,
                                               fused_dense_shape)
     if op.template != "fused_dense":
@@ -523,13 +522,8 @@ def _bind_fused_dense(op, ctx: BindContext):
         # provenance: the executor only overrides its built-in int8
         # block defaults for configs that were actually searched
         op.attrs_opt["tuned"] = True
-    elif rows <= FLATTEN_ROWS and max(d_in, d_out) <= FLATTEN_DIM:
-        op.attrs_opt["variant"] = "flattened"
     else:
-        op.attrs_opt["variant"] = "looped"
-        op.attrs_opt["bm"] = _pick_block(rows, 512)
-        op.attrs_opt["bn"] = _pick_block(d_out, 512)
-        op.attrs_opt["bk"] = _pick_block(d_in, 2048)
+        op.attrs_opt.update(fused_dense_default(rows, d_in, d_out))
 
 
 def _bind_gravnet_aggregate(op, ctx: BindContext):

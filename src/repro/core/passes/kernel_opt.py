@@ -53,6 +53,26 @@ def _pick_block(v: int, cap: int) -> int:
     return p
 
 
+def _tile(v: int, cap: int, align: int) -> int:
+    """A Mosaic-legal tile for one block dimension: the whole dimension
+    when it is no wider than one ``align`` tile, else the largest
+    power of two up to ``cap`` (a multiple of ``align``). The TPU
+    lowering requires a block's last two dims to be multiples of
+    (8, 128) or equal to the (padded) array's; a power of two below
+    the alignment would pad the array past the block and be refused."""
+    return v if v <= align else _pick_block(v, cap)
+
+
+def fused_dense_default(rows: int, d_in: int, d_out: int) -> dict:
+    """The untuned fused-dense binding: flattened for trigger-scale
+    matmuls, else the looped variant with tiles that compile on the
+    TPU (rows on 8-row sublanes, d_in/d_out on 128-wide lanes)."""
+    if rows <= FLATTEN_ROWS and max(d_in, d_out) <= FLATTEN_DIM:
+        return {"variant": "flattened"}
+    return {"variant": "looped", "bm": _tile(rows, 512, 8),
+            "bn": _tile(d_out, 512, 128), "bk": _tile(d_in, 2048, 128)}
+
+
 def fused_dense_shape(op, n_rows: int, batch: int = 1) -> tuple[int, int, int]:
     """(rows, d_in, d_out) of the matmul this op launches per step —
     the tuning-cache problem shape (shared with the autotuner).

@@ -40,6 +40,8 @@ class Requirements:
     target_throughput: float = 1.0e6     # events / s / replica-group
     max_latency_s: float | None = None   # trigger budget (paper: 10 µs)
     platform: str = "tpu"                # 'tpu' | 'cpu'
+    # jax.Device.device_kind: picks the chip's peaks on 'tpu'
+    device_kind: str | None = hw.V5E
     design_point: int = 3                # ① ② ③
     n_hits: int = 128                    # graph size per event
     precision_policy: str = "mixed"      # 'fp' | 'mixed' (paper: 16b/8b)
@@ -60,10 +62,12 @@ def _mxu_efficiency(op, n_rows: int, n_hits: int = 128) -> float:
     return eff(op, n_rows, n_hits) if eff is not None else 1.0
 
 
-def segment_time(ops, n_hits: int, p: int, platform: str = "tpu") -> float:
+def segment_time(ops, n_hits: int, p: int, platform: str = "tpu",
+                 device_kind: str | None = hw.V5E) -> float:
     """Seconds for one segment step processing p events."""
     if platform == "tpu":
-        peak_mxu, peak_vpu, bw = hw.PEAK_FLOPS_BF16, VPU_PEAK, hw.HBM_BW
+        peaks = hw.chip_peaks(device_kind)
+        peak_mxu, peak_vpu, bw = peaks.flops_bf16, VPU_PEAK, peaks.hbm_bw
     else:  # calibrated-order-of-magnitude CPU constants (relative use only)
         peak_mxu = peak_vpu = 5e10
         bw = 2e10
@@ -98,7 +102,8 @@ def parallelize(g: Graph, req: Requirements) -> Graph:
             tgt = ops[0].target
             p = p_mxu if tgt == "mxu" else p_xla
             chunks = b // p
-            total += chunks * segment_time(ops, req.n_hits, p, req.platform)
+            total += chunks * segment_time(ops, req.n_hits, p, req.platform,
+                                           req.device_kind)
         return (b / total if total > 0 else float("inf")), total
 
     max_lat = req.max_latency_s or float("inf")

@@ -477,7 +477,7 @@ class CompiledPipeline:
 
                 def join(v):
                     return jax.tree_util.tree_map(
-                        lambda a: a.reshape(nchunk * p_seg, *a.shape[1:]), v)
+                        lambda a: a.reshape(nchunk * p_seg, *a.shape[2:]), v)
 
                 out = jax.lax.map(lambda ef: body(ef[0], ef[1]),
                                   (split(env_in), split(feeds)))
@@ -491,13 +491,15 @@ class CompiledPipeline:
             plans.append((seg, ins, outs, make_seg_fn(seg, ins, outs)))
         self._plans = plans
 
+        def whole(feeds):
+            env: dict[str, Any] = {}
+            for seg, ins, outs, fn in plans:
+                env.update(fn({i: env[i] for i in ins if i in env},
+                              feeds))
+            return env[g.outputs()[0].name]
+
+        self._compose = whole
         if self._fused:
-            def whole(feeds):
-                env: dict[str, Any] = {}
-                for seg, ins, outs, fn in plans:
-                    env.update(fn({i: env[i] for i in ins if i in env},
-                                  feeds))
-                return env[g.outputs()[0].name]
             self._whole = jax.jit(whole)
             self._seg_fns = None
         else:
@@ -505,12 +507,23 @@ class CompiledPipeline:
             self._seg_fns = [(seg, ins, outs, jax.jit(fn))
                              for seg, ins, outs, fn in plans]
 
+    def lower(self, feeds):
+        """Lower one micro-batch launch as a single program, for
+        inspection: ``feeds`` are arrays or ``jax.ShapeDtypeStruct``s
+        (possibly placed on a described device), and
+        ``.compile().as_text()`` shows the kernels the device runs."""
+        return (self._whole or jax.jit(self._compose)).lower(feeds)
+
     # calibration + weight quantization ------------------------------------
     def calibrate(self, feeds):
         """Run fp over a calibration batch, set activation scales, quantize
-        int8 weights (per-output-channel)."""
+        int8 weights (per-output-channel). The recording run uses the
+        jnp reference kernels (as ``_calibrate_block`` does), so every
+        kernel backend bakes the same scales and its outputs differ
+        from the reference only by kernel arithmetic."""
         record: dict[str, float] = {}
-        _, env = self._ex.run(feeds, force_fp=True, record=record)
+        _, env = _Executor(self.graph, self.req, "xla").run(
+            feeds, force_fp=True, record=record)
         for op in self.graph:
             if op.name in record:
                 op.attrs["act_scale"] = activation_scale(record[op.name])
@@ -594,6 +607,9 @@ class CompiledPipeline:
     def resource_report(self):
         """Table-I analogue: per-segment FLOPs/bytes/VMEM occupancy."""
         n = self.req.n_hits
+        # occupancy against the target chip's VMEM (v5e off-TPU)
+        vmem_bytes = hw.chip_peaks(self.req.device_kind
+                                   or hw.V5E).vmem_bytes
         rows = []
         for seg in self.segments:
             ops_ = [self.graph[o] for o in seg["ops"]]
@@ -610,9 +626,10 @@ class CompiledPipeline:
                 "ops": len(ops_), "flops_per_event": fl,
                 "act_bytes_per_event": by, "weight_bytes": wb,
                 "vmem_working_set": vmem,
-                "vmem_util": vmem / hw.VMEM_BYTES,
+                "vmem_util": vmem / vmem_bytes,
                 "time_s_per_step": segment_time(ops_, n, p,
-                                                self.req.platform),
+                                                self.req.platform,
+                                                self.req.device_kind),
             })
         return rows
 
@@ -669,7 +686,16 @@ def deploy(model_graph: Graph, req: Requirements, *,
     the padded ``{hits, mask}`` feeds and reproduces the padded
     pipeline's output structure."""
     import os as _os
-    backend = (kernel_backend or _os.environ.get("REPRO_BACKEND")
+    env = _os.environ.get("REPRO_BACKEND")
+    if (kernel_backend is None and env and env != "pallas"
+            and jax.default_backend() == "tpu"):
+        # REPRO_BACKEND is the CPU test lever; on a TPU it would
+        # silently swap the compiled kernels for a reference path
+        raise ValueError(
+            f"REPRO_BACKEND={env!r} on a TPU would replace the compiled "
+            "Pallas kernels; unset it, or pass kernel_backend= "
+            "explicitly")
+    backend = (kernel_backend or env
                or ("pallas" if req.platform == "tpu" else "xla"))
     if ragged and req.precision_policy == "mixed":
         raise NotImplementedError(
@@ -762,6 +788,7 @@ class BucketedPipeline:
             raise ValueError("BucketedPipeline: no bucket executables")
         self.pipes = {b: pipes[b] for b in sorted(pipes)}
         self.buckets = tuple(sorted(pipes))
+        self.backend = self.pipes[self.buckets[0]].backend
         self.microbatch = microbatch
         self.mask_feed = mask_feed
         # example feeds (calibration slice) drive warmup compilation
@@ -903,6 +930,7 @@ class RaggedPipeline:
             raise ValueError("RaggedPipeline needs a raggedized graph "
                              "(deploy(ragged=True) builds one)")
         self.pipe = pipe
+        self.backend = pipe.backend
         # bins per launch = the executable's microbatch, so every call
         # is exactly one chunk (no zero-padding: an all-zero pad bin
         # would alias segment id 0)

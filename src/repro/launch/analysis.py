@@ -101,16 +101,17 @@ def roofline(terms: dict, *, n_chips: int, model_flops: float) -> dict:
     totals here treat cost_analysis as PER-DEVICE work and multiply terms
     accordingly — see EXPERIMENTS.md §Methodology for validation).
     """
-    t_compute = terms["flops"] / hw.PEAK_FLOPS_BF16
-    t_memory = terms["bytes"] / hw.HBM_BW
-    t_coll = terms["collective_bytes"] / hw.ICI_BW
+    peaks = hw.chip_peaks(hw.V5E)
+    t_compute = terms["flops"] / peaks.flops_bf16
+    t_memory = terms["bytes"] / peaks.hbm_bw
+    t_coll = terms["collective_bytes"] / peaks.ici_bw
     dominant = max(
         (("compute", t_compute), ("memory", t_memory),
          ("collective", t_coll)), key=lambda kv: kv[1])[0]
     step_time = max(t_compute, t_memory, t_coll)
     useful = model_flops / max(terms["flops"] * n_chips, 1.0)
     mfu = (model_flops / n_chips / max(step_time, 1e-12)
-           ) / hw.PEAK_FLOPS_BF16
+           ) / peaks.flops_bf16
     return {
         "t_compute_s": t_compute, "t_memory_s": t_memory,
         "t_collective_s": t_coll, "dominant": dominant,

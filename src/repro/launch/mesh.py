@@ -7,15 +7,50 @@ sets XLA_FLAGS for 512 placeholder host devices before first jax init.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
-# TPU v5e hardware constants used by the roofline model and the
-# parallelization pass's throughput estimator.
-PEAK_FLOPS_BF16 = 197e12      # per chip, FLOP/s
-PEAK_FLOPS_INT8 = 394e12      # per chip, OP/s (int8 MXU)
-HBM_BW = 819e9                # per chip, B/s
-ICI_BW = 50e9                 # per link, B/s
-VMEM_BYTES = 128 * 1024 * 1024
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one TPU generation."""
+    flops_bf16: float       # FLOP/s (bf16 MXU)
+    ops_int8: float         # OP/s (int8 MXU)
+    hbm_bw: float           # B/s
+    ici_bw: float           # B/s per link
+    vmem_bytes: int
+    source: str
+
+
+V5E = "TPU v5 lite"         # jax.Device.device_kind of a TPU v5e chip
+
+# Peaks keyed by ``jax.Device.device_kind``, used by the roofline model
+# and the parallelization pass's throughput estimator. A kind that is
+# not here is an error (``chip_peaks``), never a default.
+CHIP_PEAKS = {
+    V5E: ChipPeaks(
+        flops_bf16=197e12, ops_int8=393e12, hbm_bw=819e9,
+        # 1,600 Gbit/s chip-to-chip over four links
+        ici_bw=50e9,
+        # VMEM is not among the published figures: the resource
+        # report's occupancy denominator, not a kernel's actual limit
+        vmem_bytes=128 * 1024 * 1024,
+        source='Google Cloud documentation, "TPU v5e" (system '
+               'architecture: per-chip peaks)'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of a TPU ``device_kind``; raises for a kind
+    with no entry in ``CHIP_PEAKS``."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device kind {device_kind!r}; "
+            f"add it to launch.mesh.CHIP_PEAKS with its source "
+            f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

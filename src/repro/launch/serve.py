@@ -1,12 +1,21 @@
 """Serving driver: ``python -m repro.launch.serve [...]``.
 
-Runs a registered model end to end on CPU: deploy it through the
-model-agnostic design flow at the chosen design point (the model joins
-via its ``core.graph_ir`` exporter — serve.py has no model-specific
-imports at module level), wrap the compiled pipeline in the real-time
-sharded trigger service (micro-batching window, strict in-order
-completion, hedged dispatch), stream synthetic events through it, and
-report throughput/latency percentiles.
+Runs a registered model end to end on the default JAX device — a TPU
+runs the compiled Pallas kernels, a CPU the jnp reference, and every
+report line names the device's platform and ``device_kind``: deploy
+it through the model-agnostic design flow at the chosen design point
+(the model joins via its ``core.graph_ir`` exporter — serve.py has no
+model-specific imports at module level), wrap the compiled pipeline in
+the real-time sharded trigger service (micro-batching window, strict
+in-order completion, hedged dispatch), stream synthetic events through
+it, and report throughput/latency percentiles.
+
+``main(argv)`` returns a summary (events released and failed, device,
+kernel backend, the deployed servables and the per-event outputs) for
+programmatic callers such as ``chip_smoke.py``. Without
+``--inject-faults`` any failed event is an error: the run exits
+non-zero. The persistent compilation cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 
 ``--model`` picks the route(s) from the serve-side model registry
 (``MODELS``; default ``ccn``). The single-model ``ccn`` selection runs
@@ -33,6 +42,8 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import pathlib
 import time
 import urllib.request
 from typing import Callable, NamedTuple
@@ -46,6 +57,58 @@ from repro.core.pipeline import deploy, deploy_bucketed
 from repro.serving import (FaultPlan, MonitorServer,
                            ShardedTriggerService, event_display,
                            write_display)
+
+# the checkout root (src/repro/launch/serve.py -> repo)
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+# ------------------------------------------------------------ platform ----
+def configure_compile_cache() -> str:
+    """Persistent compilation cache for an entry point; call it before
+    the first compile. When ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    owns the cache and nothing is set here; otherwise the cache goes
+    to ``<repo>/.jax_cache`` — a fixed path, because the path is part
+    of what a later run must find again. Returns the directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    # cache every executable: the trigger's kernels each compile in
+    # well under JAX's default one-second threshold
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+
+
+def device_info() -> dict:
+    """The default device as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def _device_label() -> str:
+    d = device_info()
+    return d["platform"] if d["kind"] == d["platform"] \
+        else f"{d['platform']} {d['kind']}"
+
+
+def _us(x) -> str:
+    """A latency in µs; undefined (None) when no event completed."""
+    return "n/a" if x is None else f"{x:.0f}us"
+
+
+def _requirements(args, **kw) -> Requirements:
+    """Deployment requirements for the device this process runs on: a
+    TPU deploys the Pallas kernels and costs the P-search with that
+    chip's published peaks (``launch.mesh.chip_peaks``)."""
+    d = device_info()
+    return Requirements(design_point=args.design_point,
+                        platform=d["platform"],
+                        device_kind=d["kind"] if d["platform"] == "tpu"
+                        else None,
+                        target_throughput=args.target_throughput,
+                        max_latency_s=2e-3, **kw)
 
 
 # ------------------------------------------------------------ model zoo ----
@@ -83,31 +146,45 @@ def _edge_events(d_in, d_edge_in=None):
     return events
 
 
-def _ccn_servable(args) -> Servable:
+def _ccn_configs(detector: str):
     from repro.core import caloclusternet as ccn
-    from repro.data.belle2 import (Belle2Config, current_detector,
-                                   generate)
-    if args.detector == "current":
-        cfg, gen_cfg = ccn.current_detector_config(), current_detector()
-    else:
-        cfg, gen_cfg = ccn.CCNConfig(), Belle2Config()
-    params = ccn.init(jax.random.PRNGKey(0), cfg)
-    graph = export_graph("caloclusternet", params, cfg)
+    from repro.data.belle2 import Belle2Config, current_detector
+    if detector == "current":
+        return ccn.current_detector_config(), current_detector()
+    return ccn.CCNConfig(), Belle2Config()
+
+
+def ccn_deployment(args, params=None) -> dict:
+    """What the CCN route deploys: the model graph (random init from
+    seed 0 unless trained ``params`` are given), its Requirements on
+    this device, the calibration feeds, and a synthetic per-event feed
+    source ``events(n, seed)``."""
+    from repro.core import caloclusternet as ccn
+    from repro.data.belle2 import generate
+    cfg, gen_cfg = _ccn_configs(args.detector)
+    if params is None:
+        params = ccn.init(jax.random.PRNGKey(0), cfg)
     calib = generate(gen_cfg, 64, seed=123)
-    req = Requirements(design_point=args.design_point, platform="cpu",
-                       precision_policy=args.precision,
-                       n_hits=cfg.n_hits,
-                       target_throughput=args.target_throughput,
-                       max_latency_s=2e-3)
-    pipe = deploy(graph, req, calibration_feeds={
-        "hits": calib["feats"], "mask": calib["mask"]})
 
     def events(n, seed):
         ev = generate(gen_cfg, n, seed=seed)
         return [{"hits": ev["feats"][i], "mask": ev["mask"][i]}
                 for i in range(n)]
 
-    return Servable("ccn", pipe, events)
+    return {"graph": export_graph("caloclusternet", params, cfg),
+            "req": _requirements(
+                args, precision_policy=args.precision, n_hits=cfg.n_hits,
+                tpu_native_gravnet=args.tpu_native_gravnet),
+            "calibration_feeds": {"hits": calib["feats"],
+                                  "mask": calib["mask"]},
+            "events": events}
+
+
+def _ccn_servable(args) -> Servable:
+    m = ccn_deployment(args)
+    return Servable("ccn", deploy(m["graph"], m["req"],
+                                  calibration_feeds=m["calibration_feeds"]),
+                    m["events"])
 
 
 def _gatedgcn_servable(args) -> Servable:
@@ -116,10 +193,7 @@ def _gatedgcn_servable(args) -> Servable:
                                   d_edge_in=4, n_classes=2)
     params = gatedgcn.init(jax.random.PRNGKey(1), cfg)
     graph = export_graph("gatedgcn", params, cfg)
-    req = Requirements(design_point=args.design_point, platform="cpu",
-                       precision_policy="fp", n_hits=_EDGE_N,
-                       target_throughput=args.target_throughput,
-                       max_latency_s=2e-3)
+    req = _requirements(args, precision_policy="fp", n_hits=_EDGE_N)
     return Servable("gatedgcn", deploy(graph, req),
                     _edge_events(cfg.d_in, cfg.d_edge_in))
 
@@ -130,10 +204,7 @@ def _graphsage_servable(args) -> Servable:
                                     n_classes=5)
     params = graphsage.init(jax.random.PRNGKey(2), cfg)
     graph = export_graph("graphsage", params, cfg)
-    req = Requirements(design_point=args.design_point, platform="cpu",
-                       precision_policy="fp", n_hits=_EDGE_N,
-                       target_throughput=args.target_throughput,
-                       max_latency_s=2e-3)
+    req = _requirements(args, precision_policy="fp", n_hits=_EDGE_N)
     return Servable("graphsage", deploy(graph, req),
                     _edge_events(cfg.d_in))
 
@@ -215,21 +286,17 @@ def _serve_multimodel(args):
                 live.remove(name)
             else:
                 futs.append(eng.submit(ev, route=name))
-    results, failed = [], 0
-    for f in futs:
-        try:
-            results.append(f.result(timeout=120))
-        except Exception:  # noqa: BLE001 — only under injected chaos
-            failed += 1
+    results, failed = _collect(futs)
     dt = time.perf_counter() - t0
     eng.drain()
-    released = len(results) + failed
+    released = len(results)
     s = eng.stats.summary()
     print(f"[serve] {released} events in {dt:.2f}s -> "
-          f"{released / dt:,.0f} ev/s (CPU, {args.replicas} replica(s) "
-          f"per route, {args.policy}, {args.loop} loop)")
-    print(f"[serve] latency p50={s['p50_us']:.0f}us "
-          f"p99={s['p99_us']:.0f}us batches={s['batches']}")
+          f"{released / dt:,.0f} ev/s ({_device_label()}, "
+          f"{args.replicas} replica(s) per route, {args.policy}, "
+          f"{args.loop} loop)")
+    print(f"[serve] latency p50={_us(s['p50_us'])} "
+          f"p99={_us(s['p99_us'])} batches={s['batches']}")
     route_rows = eng.route_summary()
     for row in route_rows:
         print(f"[serve]   route {row['route']}: "
@@ -254,13 +321,43 @@ def _serve_multimodel(args):
     if released < args.events:
         raise SystemExit("multi-model serving released fewer events "
                          "than were submitted")
+    _fail_unless_chaos(fk, failed)
     if fk["faults"] is None and any(
             row["completed"] != row["submitted"] for row in route_rows):
         raise SystemExit("multi-model serving released fewer events "
                          "than were submitted")
+    return _summary(servables, results, failed, routes=route_rows)
 
 
-def main():
+def _collect(futs):
+    """Wait for every future in submission order; a failed event is
+    kept as ``None`` so results stay aligned with submissions."""
+    results, failed = [], 0
+    for f in futs:
+        try:
+            results.append(f.result(timeout=120))
+        except Exception:  # noqa: BLE001 — counted, judged by caller
+            results.append(None)
+            failed += 1
+    return results, failed
+
+
+def _fail_unless_chaos(fk, failed: int):
+    """Without a fault plan a failed event is a broken deployment,
+    not a statistic: exit non-zero."""
+    if fk["faults"] is None and failed:
+        raise SystemExit(f"{failed} event(s) failed without injected "
+                         "faults")
+
+
+def _summary(servables, results, failed, **extra) -> dict:
+    backends = sorted({s.pipe.backend for s in servables})
+    return {"released": len(results), "failed": failed,
+            "device": device_info(), "backend": ",".join(backends),
+            "servables": servables, "outputs": results, **extra}
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", nargs="+", default=["ccn"],
                     choices=sorted(MODELS), metavar="NAME",
@@ -281,7 +378,8 @@ def main():
                     default="mixed")
     ap.add_argument("--events", type=int, default=512)
     ap.add_argument("--target-throughput", type=float, default=1e5,
-                    help="events/s target for the P-search (CPU scale)")
+                    help="events/s target for the P-search, costed "
+                         "with the device's peaks")
     ap.add_argument("--tpu-native-gravnet", action="store_true")
     ap.add_argument("--train-steps", type=int, default=40)
     ap.add_argument("--event-display", default=None, metavar="PATH",
@@ -357,21 +455,21 @@ def main():
                          "calibrated int8 dense chain (and its tuning "
                          "keys, bit-for-bit) instead of the quantized "
                          "megakernel; fp deployments still fuse")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> dict:
+    """Serve once and return the run's summary (see the module
+    docstring); ``argv`` defaults to the command line."""
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
 
     if args.model != ["ccn"]:
         return _serve_multimodel(args)
 
     from repro.core import caloclusternet as ccn
-    from repro.data.belle2 import (Belle2Config, current_detector,
-                                   generate)
-    if args.detector == "current":
-        cfg = ccn.current_detector_config()
-        gen_cfg = current_detector()
-    else:
-        cfg = ccn.CCNConfig()
-        gen_cfg = Belle2Config()
-
+    from repro.data.belle2 import generate
+    cfg, gen_cfg = _ccn_configs(args.detector)
     params = ccn.init(jax.random.PRNGKey(0), cfg)
     if args.train_steps > 0:   # brief condensation training so the
         import jax.numpy as jnp    # demo's decisions are meaningful
@@ -402,15 +500,9 @@ def main():
             params, opt, l = _step(params, opt, b)
         print(f"[serve] warm-trained {args.train_steps} steps, "
               f"loss {float(l):.3f}")
-    graph = export_graph("caloclusternet", params, cfg)
-    calib = generate(gen_cfg, 64, seed=123)
-    feeds = {"hits": calib["feats"], "mask": calib["mask"]}
-    req = Requirements(design_point=args.design_point, platform="cpu",
-                       precision_policy=args.precision,
-                       n_hits=cfg.n_hits,
-                       target_throughput=args.target_throughput,
-                       max_latency_s=2e-3,
-                       tpu_native_gravnet=args.tpu_native_gravnet)
+    model = ccn_deployment(args, params)
+    graph, req = model["graph"], model["req"]
+    feeds = model["calibration_feeds"]
     cache = None
     if args.tuning_cache or args.tune:
         from repro.tuning import TuningCache
@@ -428,22 +520,22 @@ def main():
     fk = _fault_kwargs(args)
     if args.buckets:
         mb = args.bucket_microbatch
-        bpipe = deploy_bucketed(graph, req, buckets=args.buckets,
-                                microbatch=mb, calibration_feeds=feeds,
-                                tuning_cache=cache,
-                                fuse_gravnet_block=fuse_block,
-                                fuse_int8=fuse_int8)
+
+        def redeploy(**kw):
+            return deploy_bucketed(
+                graph, req, buckets=args.buckets, microbatch=mb,
+                calibration_feeds=feeds, tuning_cache=cache,
+                fuse_gravnet_block=fuse_block, fuse_int8=fuse_int8, **kw)
+
+        bpipe = redeploy()
         if args.tune:
             fresh = _tune_and_rebind(
                 cache, args,
                 [(p.graph, b, mb, p.backend)
-                 for b, p in bpipe.pipes.items()],
-                lambda: deploy_bucketed(
-                    graph, req, buckets=args.buckets, microbatch=mb,
-                    calibration_feeds=feeds, tuning_cache=cache,
-                    fuse_gravnet_block=fuse_block, fuse_int8=fuse_int8))
+                 for b, p in bpipe.pipes.items()], redeploy)
             if fresh is not None:
                 bpipe = fresh
+        served = bpipe
         print(f"[serve] deployed design ③{args.design_point} "
               f"buckets={bpipe.buckets} microbatch={mb} "
               f"(one batch-packed executable per bucket)")
@@ -454,18 +546,20 @@ def main():
         print(f"[serve] bucket executables pre-compiled at startup: "
               f"{sum(r.warmed for r in eng.replicas)}")
     else:
-        pipe = deploy(graph, req, calibration_feeds=feeds,
-                      tuning_cache=cache, fuse_gravnet_block=fuse_block,
-                      fuse_int8=fuse_int8)
+        def redeploy(**kw):
+            return deploy(graph, req, calibration_feeds=feeds,
+                          tuning_cache=cache,
+                          fuse_gravnet_block=fuse_block,
+                          fuse_int8=fuse_int8, **kw)
+
+        pipe = redeploy()
         if args.tune:
             fresh = _tune_and_rebind(
                 cache, args, [(pipe.graph, cfg.n_hits, 1, pipe.backend)],
-                lambda: deploy(graph, req, calibration_feeds=feeds,
-                               tuning_cache=cache,
-                               fuse_gravnet_block=fuse_block,
-                               fuse_int8=fuse_int8))
+                redeploy)
             if fresh is not None:
                 pipe = fresh
+        served = pipe
         print(f"[serve] deployed design ③{args.design_point} "
               f"segments={len(pipe.segments)} P={pipe.par}")
 
@@ -473,8 +567,7 @@ def main():
             return pipe({"hits": batch["hits"], "mask": batch["mask"]})
 
         # warmup compile
-        warm = {"hits": calib["feats"][:pipe.microbatch],
-                "mask": calib["mask"][:pipe.microbatch]}
+        warm = {k: v[:pipe.microbatch] for k, v in feeds.items()}
         infer(warm)
 
         warmup_fn = None
@@ -504,13 +597,7 @@ def main():
                                 "mask": events["mask"][i]},
                                truth=bool(truth[i]) if monitoring
                                else None))
-    results, failed = [], 0
-    for f in futs:
-        try:
-            results.append(f.result(timeout=120))
-        except Exception:  # noqa: BLE001 — only under injected chaos
-            results.append(None)
-            failed += 1
+    results, failed = _collect(futs)
     dt = time.perf_counter() - t0
     eng.drain()
     s = eng.stats.summary()
@@ -519,15 +606,15 @@ def main():
     eff = float((trig & truth).sum() / max(truth.sum(), 1))
     fake = float((trig & ~truth).sum() / max((~truth).sum(), 1))
     print(f"[serve] {args.events} events in {dt:.2f}s -> "
-          f"{args.events / dt:,.0f} ev/s (CPU, "
+          f"{args.events / dt:,.0f} ev/s ({_device_label()}, "
           f"{args.replicas} replica(s), {args.policy}, "
           f"{args.loop} loop)")
-    print(f"[serve] latency p50={s['p50_us']:.0f}us "
-          f"p99={s['p99_us']:.0f}us batches={s['batches']}")
+    print(f"[serve] latency p50={_us(s['p50_us'])} "
+          f"p99={_us(s['p99_us'])} batches={s['batches']}")
     bud = s["budget"]
-    print(f"[serve] budget queue_wait={bud['queue_wait_us_mean']:.0f}us "
-          f"dispatch={bud['dispatch_us_mean']:.0f}us "
-          f"compute={bud['compute_us_mean']:.0f}us")
+    print(f"[serve] budget queue_wait={_us(bud['queue_wait_us_mean'])} "
+          f"dispatch={_us(bud['dispatch_us_mean'])} "
+          f"compute={_us(bud['compute_us_mean'])}")
     for rs in s["per_replica"]:
         print(f"[serve]   replica {rs['replica_id']}: "
               f"{rs['completed']} events, {rs['batches']} batches, "
@@ -574,6 +661,12 @@ def main():
     if server is not None:
         server.close()
     eng.close()
+    _fail_unless_chaos(fk, failed)
+    return _summary(
+        [Servable("ccn", served, model["events"])], results, failed,
+        events=[{"hits": events["feats"][i], "mask": events["mask"][i]}
+                for i in range(args.events)],
+        redeploy=redeploy)
 
 
 if __name__ == "__main__":

@@ -157,8 +157,9 @@ class ShardedTriggerService:
     instead of paying jit tracing on the first real event. It runs
     once per *distinct device* (the jit cache is per-device, so
     thread-backed replicas sharing one device would re-execute an
-    already-hot cache N times for nothing). Best-effort: failures are
-    swallowed and the replicas start anyway.
+    already-hot cache N times for nothing). A warm-up that raises
+    fails the service's start: the replicas already built are closed
+    and the error propagates.
 
     ``monitor``: opt-in real-time monitoring (paper §III-B's
     visualization pipeline). ``True`` attaches one ``TriggerMonitor``
@@ -374,21 +375,21 @@ class ShardedTriggerService:
             key = (dev, id(warmup_fns[i]))
             wf = warmup_fns[i] if key not in warmed else None
             warmed.add(key)
-            self.replicas.append(
-                engine_cls(fn, self._releaser, microbatch=microbatch,
-                           window_s=window_s, queue_depth=queue_depth,
-                           hedge_after_s=hedge_after_s, device=dev,
-                           replica_id=i, inflight=inflight,
-                           warmup_fn=wf,
-                           monitor=self.monitors[i]
-                           if self.monitors else None,
-                           truth_map=self._truth
-                           if self.monitors else None,
-                           faults=faults,
-                           health=self.healths[i]
-                           if self.healths else None,
-                           on_batch_failure=on_batch_failure,
-                           shed=shed))
+            try:
+                self.replicas.append(engine_cls(
+                    fn, self._releaser, microbatch=microbatch,
+                    window_s=window_s, queue_depth=queue_depth,
+                    hedge_after_s=hedge_after_s, device=dev,
+                    replica_id=i, inflight=inflight, warmup_fn=wf,
+                    monitor=self.monitors[i] if self.monitors else None,
+                    truth_map=self._truth if self.monitors else None,
+                    faults=faults,
+                    health=self.healths[i] if self.healths else None,
+                    on_batch_failure=on_batch_failure, shed=shed))
+            except BaseException:
+                for r in self.replicas:   # no lane outlives a failed
+                    r.close()             # start
+                raise
         if self.buckets:
             self._bucket_groups = {
                 b: self.replicas[gi * n_replicas:(gi + 1) * n_replicas]

@@ -274,22 +274,20 @@ class ReplicaEngine:
         self.inflight = inflight
         self.replica_id = replica_id
         self.stats = ServingStats(replica_id=replica_id)
-        # warm-up (e.g. replaying tuning-cache winners so the jit cache
-        # is hot) runs BEFORE the batcher thread starts accepting work:
-        # the first real event must never pay compilation. Best-effort —
-        # a failing warm-up must not kill the lane.
+        # warm-up (e.g. pre-compiling the bucket executables) runs
+        # BEFORE the batcher thread starts accepting work: the first
+        # real event must never pay compilation. A warm-up that raises
+        # fails service start — an executable that cannot compile
+        # would otherwise fail every batch it is given.
         self.warmed = 0
         if warmup_fn is not None:
-            try:
-                if self.device is not None:
-                    import jax
-                    with jax.default_device(self.device):
-                        out = warmup_fn()
-                else:
+            if self.device is not None:
+                import jax
+                with jax.default_device(self.device):
                     out = warmup_fn()
-                self.warmed = int(out) if isinstance(out, int) else 1
-            except Exception:  # noqa: BLE001
-                self.warmed = 0
+            else:
+                out = warmup_fn()
+            self.warmed = int(out) if isinstance(out, int) else 1
         self._q: queue.Queue = queue.Queue(maxsize=queue_depth)
         self._stop = threading.Event()
         self._count_lock = threading.Lock()

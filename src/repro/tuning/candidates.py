@@ -14,10 +14,12 @@ would pick without tuning; the autotuner only switches away from it on
 a measured, above-noise win, so an unlucky timing run can never make
 things worse than today's behavior.
 
-Block candidates are powers of two: the kernels' wrappers pad operands
-to block multiples, TPU lanes are 128 wide, and sublane tiles are 8
-deep — powers of two keep every candidate launchable on both the
-interpret and Mosaic paths.
+Fused-dense blocks must compile on the TPU, whose lowering takes a
+block's last two dims only as multiples of (8, 128) or as the whole
+(padded) array dim. The kernels' wrappers pad operands to block
+multiples, so the searched blocks are powers of two of at least 8 rows
+and 128 lanes, and the default spans a dim narrower than one tile
+(``kernel_opt.fused_dense_default``).
 """
 from __future__ import annotations
 
@@ -46,12 +48,7 @@ def _dedup_keep_order(cands: list[dict]) -> list[dict]:
 def default_fused_dense(rows: int, d_in: int, d_out: int) -> dict:
     """The untuned heuristic from ``kernel_opt`` (kept in one place so
     the bit-for-bit fallback and the search baseline cannot drift)."""
-    if rows <= _ko.FLATTEN_ROWS and max(d_in, d_out) <= _ko.FLATTEN_DIM:
-        return {"variant": "flattened"}
-    return {"variant": "looped",
-            "bm": _ko._pick_block(rows, 512),
-            "bn": _ko._pick_block(d_out, 512),
-            "bk": _ko._pick_block(d_in, 2048)}
+    return _ko.fused_dense_default(rows, d_in, d_out)
 
 
 def fused_dense_candidates(rows: int, d_in: int, d_out: int,

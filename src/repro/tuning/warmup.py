@@ -7,10 +7,10 @@ catastrophic. The tuning cache already knows exactly which
 ``warm_from_cache`` replays each cached winner once with synthetic
 operands before the replica accepts traffic, populating the jit cache.
 
-Warm-up is strictly best-effort: a cache entry that no longer matches
-the installed kernels (renamed knob, impossible shape) is skipped, and
-the replica starts regardless — the cache can make startup faster,
-never break it.
+A cache entry that no longer matches the installed kernels (renamed
+knob, impossible shape) is skipped and counted: the replay prints how
+many it skipped, and the replica starts — a stale cache can make
+startup slower, never break it.
 """
 from __future__ import annotations
 
@@ -205,8 +205,9 @@ def _replay(key, config) -> None:
 def warm_from_cache(cache: TuningCache, *, backend: str | None = None,
                     kernels: tuple[str, ...] | None = None) -> int:
     """Replay every cached winner (optionally filtered by backend /
-    kernel family) once; returns how many entries were warmed."""
-    warmed = 0
+    kernel family) once; returns how many entries were warmed and
+    prints how many stale entries it skipped."""
+    warmed, skipped = 0, []
     for key, entry in sorted(cache.entries().items(),
                              key=lambda kv: kv[0].encode()):
         if backend is not None and key.backend != backend:
@@ -216,8 +217,13 @@ def warm_from_cache(cache: TuningCache, *, backend: str | None = None,
         try:
             _replay(key, entry.config)
         except Exception:   # noqa: BLE001 — stale entry must not block start
+            skipped.append(key.encode())
             continue
         warmed += 1
+    if skipped:
+        print(f"[tuning] warm-up skipped {len(skipped)} stale cache "
+              f"entr{'y' if len(skipped) == 1 else 'ies'}: "
+              f"{', '.join(skipped)}")
     return warmed
 
 

@@ -23,6 +23,16 @@ boundary values — the logic lives here once, so every test states
                              quantum and a small fraction of affected
                              elements (``int8_flip_tolerance`` derives
                              the quantum from the baked scales).
+- ``assert_selection_close``: two programs that select k-NN
+                             neighbors from coordinates computed in a
+                             different order — every hit within the
+                             dtype's tolerance except the few whose
+                             selection flipped at a near-tie.
+- ``assert_fixture_match``: a golden fixture — integer, bool and
+                             index arrays exactly, float arrays by
+                             their dtype's rtol/atol (a fixture pins
+                             what the computation means, not the bits
+                             one XLA CPU build produced).
 - ``backend_sweep``        : the backends a differential test should
                              run — ``xla`` (jnp reference), the
                              interpret-mode Pallas body, and the real
@@ -90,6 +100,46 @@ def assert_close(got, want, *, dtype=None, rtol: float | None = None,
         atol = tat if atol is None else atol
     np.testing.assert_allclose(_as64(g), _as64(w), rtol=rtol, atol=atol,
                                err_msg=context)
+
+
+def assert_selection_close(got, want, *, dtype="float32",
+                           max_flip_frac: float = 0.05,
+                           context: str = "") -> None:
+    """Per-hit comparison for outputs downstream of k-NN selection.
+    Coordinates whose last bits differ can reorder two candidates at a
+    near-tie, which changes that hit's neighbor set (and its
+    neighbors' aggregates). Rows (the last axis is features) must agree
+    within ``dtype``'s tolerance except at most ``max_flip_frac`` of
+    them; the rest must still be finite."""
+    g, w = _as64(got), _as64(want)
+    assert g.shape == w.shape, (context, g.shape, w.shape)
+    rtol, atol = tolerance(dtype)
+    bad = np.abs(g - w) > atol + rtol * np.abs(w)
+    rows = bad.reshape(-1, w.shape[-1]).any(axis=1)
+    frac = float(rows.mean()) if rows.size else 0.0
+    tag = f" ({context})" if context else ""
+    assert np.isfinite(g).all(), f"non-finite output{tag}"
+    if frac > max_flip_frac:
+        raise AssertionError(
+            f"selection mismatch{tag}: {frac:.1%} of hits differ beyond "
+            f"f32 tolerance (> {max_flip_frac:.1%} allowed)")
+
+
+def assert_fixture_match(got: dict, want: dict, *, dtype=None,
+                         context: str = "") -> None:
+    """Compare a freshly generated fixture with the committed one:
+    same array names; integer/bool arrays (indices, counts, quantized
+    weights, decisions) exactly; float arrays within ``dtype``'s (or
+    their own dtype's) tolerance."""
+    assert set(got) == set(want), (sorted(got), sorted(want))
+    for name in sorted(want):
+        g, w = np.asarray(got[name]), np.asarray(want[name])
+        tag = f"{context}/{name}" if context else name
+        if np.issubdtype(w.dtype, np.floating):
+            assert g.shape == w.shape, (tag, g.shape, w.shape)
+            assert_close(g, w, dtype=dtype or w.dtype, context=tag)
+        else:
+            assert_bitwise(g, w, context=tag)
 
 
 def ulp_distance(got, want) -> np.ndarray:

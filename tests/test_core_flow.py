@@ -273,3 +273,79 @@ def test_condensation_loss_decreases(setup):
     p2 = jax.tree_util.tree_map(lambda p, g: p - 0.05 * g, params, grads)
     l1 = loss_fn(p2)
     assert float(l1) < float(l0)
+
+
+# --------------------------------------------------- chip-facing flow ----
+def _gatedgcn_graph():
+    from repro.core.graph_ir import export_graph
+    from repro.models.gnn import gatedgcn
+    gcfg = gatedgcn.GatedGCNConfig(n_layers=4, d_hidden=32, d_in=8,
+                                   d_edge_in=4, n_classes=2)
+    return export_graph("gatedgcn",
+                        gatedgcn.init(jax.random.PRNGKey(1), gcfg), gcfg)
+
+
+def test_segments_with_different_p_rejoin_the_micro_batch():
+    """On the chip's peaks the P-search gives MXU and XLA segments
+    different P; a segment with P < microbatch runs its events in
+    chunks, whose results must rejoin as one (microbatch, ...) batch."""
+    from repro.launch.serve import _edge_events
+    g = _gatedgcn_graph()
+    evs = _edge_events(8, 4)(8, 0)
+    feeds = {k: np.stack([e[k] for e in evs]) for k in evs[0]}
+    outs = {}
+    for plat in ("tpu", "cpu"):
+        req = Requirements(design_point=3, platform=plat, n_hits=64,
+                           precision_policy="fp", target_throughput=1e5,
+                           max_latency_s=2e-3)
+        pipe = deploy(g, req, kernel_backend="xla")
+        par = pipe.par
+        outs[plat] = (par["P_mxu"], par["P_xla"], pipe(feeds)["logits"])
+    assert outs["tpu"][0] != outs["tpu"][1]      # the chunked case
+    assert outs["tpu"][2].shape == (8, 64, 2)
+    np.testing.assert_allclose(outs["tpu"][2], outs["cpu"][2],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_tpu_cost_model_needs_a_known_device_kind(setup):
+    params = setup[0]
+    g = map_templates(partition(fuse(ccn.to_graph(params, CFG))))
+    req = Requirements(platform="tpu", device_kind="TPU v1 imaginary",
+                       n_hits=CFG.n_hits)
+    with pytest.raises(ValueError, match="TPU v1 imaginary"):
+        parallelize(g, req)
+    # the CPU platform keeps its relative constants, whatever the kind
+    parallelize(g, Requirements(platform="cpu", device_kind=None,
+                                n_hits=CFG.n_hits))
+
+
+def test_deploy_refuses_reference_backend_env_on_tpu(setup, monkeypatch):
+    """REPRO_BACKEND is a CPU test lever: on a TPU it must not swap the
+    compiled kernels for a reference path in silence."""
+    g = ccn.to_graph(setup[0], CFG)
+    req = Requirements(platform="tpu", precision_policy="fp",
+                       n_hits=CFG.n_hits)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for env in ("xla", "pallas_interpret"):
+        monkeypatch.setenv("REPRO_BACKEND", env)
+        with pytest.raises(ValueError, match="REPRO_BACKEND"):
+            deploy(g, req)
+    # an explicit kernel_backend is a choice, not a silent switch
+    assert deploy(g, req, kernel_backend="xla").backend == "xla"
+
+
+def test_calibration_scales_do_not_depend_on_kernel_backend(setup):
+    """Calibration records on the jnp reference, so a Pallas and an
+    xla deployment bake the same int8 scales and differ only in kernel
+    arithmetic."""
+    params, feats, mask, _ = setup
+    g = ccn.to_graph(params, CFG)
+    req = Requirements(platform="cpu", precision_policy="mixed",
+                       n_hits=CFG.n_hits, target_throughput=1e4)
+    scales = []
+    for backend in ("xla", "pallas_interpret"):
+        pipe = deploy(g, req, kernel_backend=backend,
+                      calibration_feeds={"hits": feats, "mask": mask})
+        scales.append({(op.name, k): v for op in pipe.graph
+                       for k, v in op.attrs.items() if k.endswith("scale")})
+    assert scales[0] and scales[0] == scales[1]

@@ -5,12 +5,14 @@ The deploy passes dispatch on registered op patterns
 shape. The contract of that refactor is that CaloClusterNet's deploy
 path did not move: ``tests/golden/ccn_flow.json`` pins the pass-emitted
 graphs (op names, templates, targets, segments, precisions, binding
-knobs) and the tuning-cache keys for every deploy mode, and
-``tests/golden/ccn_flow_outputs.npz`` pins the fused f32 and calibrated
-int8 outputs byte-for-byte. The committed fixtures were generated with
-the *pre-refactor* passes, so regenerating them in-process and
-comparing proves the pattern-keyed passes reproduce the legacy flow
-bit-for-bit.
+knobs, calibrated scales) and the tuning-cache keys for every deploy
+mode, and ``tests/golden/ccn_flow_outputs.npz`` pins the fused f32 and
+calibrated int8 outputs. Structure, knobs, keys, decisions and counts
+compare exactly; calibrated scales and float outputs within f32
+tolerance, since their last bits belong to the XLA CPU build that ran
+them. The JSON's ``_meta`` records the jax version that generated both
+fixtures: parameters come from ``jax.random``, so a jax whose PRNG
+changed needs them regenerated.
 
 Regenerate (after an *intentional* flow change) with:
 
@@ -25,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _numerics import assert_bitwise, assert_close, assert_selection_close
 
 from repro.core import caloclusternet as ccn
 from repro.core.graph_ir import Graph, Operator
@@ -81,6 +84,23 @@ def _graph_record(g: Graph) -> list[dict]:
     } for op in g]
 
 
+def _assert_record_match(got, want, where: str):
+    """Exact structure; floats (calibrated scales) within f32
+    tolerance."""
+    if isinstance(want, float) and isinstance(got, float):
+        assert_close(got, want, dtype="float32", context=where)
+    elif isinstance(want, dict) and isinstance(got, dict):
+        assert sorted(got) == sorted(want), f"{where} diverged"
+        for k in want:
+            _assert_record_match(got[k], want[k], f"{where}.{k}")
+    elif isinstance(want, list) and isinstance(got, list):
+        assert len(got) == len(want), f"{where} diverged"
+        for i, (g, w) in enumerate(zip(got, want)):
+            _assert_record_match(g, w, f"{where}[{i}]")
+    else:
+        assert got == want, f"{where} diverged: {got!r} != {want!r}"
+
+
 def _key_record(g: Graph) -> dict:
     return {f"{backend}/batch{batch}": [
         k.encode() for k in graph_kernel_problems(
@@ -127,6 +147,7 @@ def golden():
     if os.environ.get("REPRO_REGEN_GOLDEN") == "1":
         GOLDEN_DIR.mkdir(parents=True, exist_ok=True)
         flow, arrays = _capture()
+        flow["_meta"] = {"jax": jax.__version__}
         with open(FLOW_JSON, "w") as f:
             json.dump(flow, f, indent=1, sort_keys=True)
             f.write("\n")
@@ -156,7 +177,7 @@ def test_graph_matches_golden(mode, golden, fresh):
     got = fresh[0][mode]["graph"]
     assert [o["name"] for o in got] == [o["name"] for o in want]
     for w, g in zip(want, got):
-        assert g == w, f"{mode}: op {w['name']} diverged"
+        _assert_record_match(g, w, f"{mode}: op {w['name']}")
 
 
 @pytest.mark.parametrize("mode", sorted(MODES))
@@ -168,12 +189,16 @@ def test_tuning_keys_match_golden(mode, golden, fresh):
 
 def test_outputs_bitwise_identical(golden, fresh):
     """Fused f32 and calibrated int8 deployed outputs reproduce the
-    pre-refactor bytes exactly."""
+    fixture: trigger decisions and cluster counts exactly, float heads
+    and cluster features within f32 tolerance up to the hits whose
+    k-NN selection flips at a near-tie."""
     want, got = golden[1], fresh[1]
     assert set(got) == set(want)
     for name in sorted(want):
-        np.testing.assert_array_equal(got[name], want[name],
-                                      err_msg=name)
+        if np.issubdtype(want[name].dtype, np.floating):
+            assert_selection_close(got[name], want[name], context=name)
+        else:
+            assert_bitwise(got[name], want[name], context=name)
 
 
 # ----------------------------------------------- unknown-op diagnostics ----

@@ -7,7 +7,8 @@ Covers the hard invariants of the tuning subsystem:
 - ``kernel_optimize`` with an *empty* cache reproduces the heuristic
   bindings bit-for-bit (tuning is an overlay, never a behavior change);
 - cached winners actually bind (and are marked as searched);
-- replica warm-up replays cached shapes at startup, best-effort;
+- replica warm-up replays cached shapes at startup (stale entries
+  skipped and reported), and a warm-up that raises fails service start;
 - the benchmark-regression comparator passes/fails correctly, and the
   harness runner exits nonzero on broken sections.
 """
@@ -267,6 +268,12 @@ def test_replica_engine_runs_warmup_before_traffic():
 
 
 def test_replica_engine_survives_failing_warmup():
+    """A warm-up that raises fails the service's start instead of
+    leaving a lane that fails every batch; the lanes built before it
+    are closed (no thread outlives the failed start), and a service
+    started without the failing warm-up serves normally."""
+    import threading
+
     import numpy as np
 
     from repro.serving import ShardedTriggerService
@@ -274,15 +281,39 @@ def test_replica_engine_survives_failing_warmup():
     def bad_warmup():
         raise RuntimeError("stale cache entry")
 
-    svc = ShardedTriggerService(
-        lambda feeds: {"y": feeds["x"] + 1.0}, n_replicas=1, microbatch=2,
-        window_s=1e-3, devices=None, warmup_fn=bad_warmup)
+    def infer(feeds):
+        return {"y": feeds["x"] + 1.0}
+
+    before = set(threading.enumerate())
+    with pytest.raises(RuntimeError, match="stale cache entry"):
+        ShardedTriggerService(
+            routes={"a": infer, "b": infer}, n_replicas=1, microbatch=2,
+            window_s=1e-3, devices=None,
+            warmup_fn={"a": lambda: 1, "b": bad_warmup})
+    leaked = [t for t in threading.enumerate()
+              if t not in before and t.is_alive()]
+    assert leaked == []
+    svc = ShardedTriggerService(infer, n_replicas=1, microbatch=2,
+                                window_s=1e-3, devices=None)
     try:
-        assert svc.replicas[0].warmed == 0
         fut = svc.submit({"x": np.zeros((2,), np.float32)})
         assert fut.result(timeout=30)["y"].sum() == 2.0
     finally:
         svc.close()
+
+
+def test_warm_from_cache_reports_skipped_entries(capsys):
+    """Stale entries may be skipped, but never silently."""
+    cache = TuningCache()
+    cache.put(fused_dense_key(16, 8, 8, "float32", "xla"),
+              {"variant": "flattened"})
+    stale = KernelKey("fused_dense", (16, 8), "float32", "xla")
+    cache.put(stale, {"variant": "flattened"})
+    assert warm_from_cache(cache) == 1
+    out = capsys.readouterr().out
+    assert "skipped 1 stale cache entry" in out and stale.encode() in out
+    assert warm_from_cache(cache, kernels=("gravnet",)) == 0
+    assert "skipped" not in capsys.readouterr().out
 
 
 # -------------------------------------------------------- regression gate ----
@@ -370,3 +401,26 @@ def test_run_harness_failing_section_exits_nonzero(monkeypatch, capsys):
     assert bench_run.main(["kernels"]) == 1
     out = capsys.readouterr().out
     assert "kernels,nan,ERROR" in out
+
+
+@pytest.mark.parametrize("rows,d_in,d_out", [
+    (1024, 32, 7), (1024, 108, 64), (1024, 64, 26), (8192, 4, 64),
+    (600, 200, 5), (4096, 1000, 300), (6, 2048, 2048)])
+def test_fused_dense_tiles_are_tpu_legal(rows, d_in, d_out):
+    """Every fused-dense block (default and searched) keeps its last
+    two dims multiples of (8, 128) or equal to the wrapper-padded
+    array dim — the TPU lowering refuses anything else."""
+    from repro.tuning.candidates import fused_dense_candidates
+
+    def legal(block, dim, align):
+        padded = -(-dim // block) * block
+        return block % align == 0 or block == padded
+
+    for cfg in fused_dense_candidates(rows, d_in, d_out):
+        if cfg["variant"] != "looped":
+            continue
+        bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
+        assert legal(bm, rows, 8), cfg            # x / out sublanes
+        assert legal(bk, d_in, 128), cfg          # x lanes
+        assert legal(bk, d_in, 8), cfg            # w sublanes
+        assert legal(bn, d_out, 128), cfg         # w / out / bias lanes
