@@ -20,6 +20,7 @@ from repro.core import caloclusternet as ccn
 from repro.core.passes.parallelize import Requirements
 from repro.core.pipeline import deploy
 from repro.data.belle2 import Belle2Config, generate
+from repro.launch.mesh import V5E
 
 N_EVENTS = 256
 
@@ -60,6 +61,7 @@ def run(detector: str = "upgrade", events: int = N_EVENTS):
         ev_s = events / t
         # derived TPU numbers from the analytic model (per chip)
         req_tpu = Requirements(design_point=dp, platform="tpu",
+                               device_kind=V5E,
                                precision_policy="mixed",
                                n_hits=cfg.n_hits, target_throughput=3e6,
                                max_latency_s=10e-6)
@@ -79,7 +81,7 @@ def run(detector: str = "upgrade", events: int = N_EVENTS):
                        tpu_native_gravnet=True)
     pipe = deploy(graph, req, calibration_feeds=calib)
     t, _ = time_fn(lambda: pipe(feeds), iters=3)
-    req_tpu = Requirements(design_point=3, platform="tpu",
+    req_tpu = Requirements(design_point=3, platform="tpu", device_kind=V5E,
                            precision_policy="mixed", n_hits=cfg.n_hits,
                            target_throughput=3e6, max_latency_s=10e-6,
                            tpu_native_gravnet=True)

@@ -16,6 +16,7 @@ from repro.core.passes.parallelize import Requirements, parallelize
 from repro.core.pipeline import CompiledPipeline, deploy
 from repro.core.quantization import apply_precision_policy
 from repro.data.belle2 import Belle2Config, generate
+from repro.launch.mesh import V5E
 
 
 def run(max_p: int = 32):
@@ -40,7 +41,7 @@ def run(max_p: int = 32):
         t, _ = time_fn(lambda: pipe(feeds))
         ev_s = 128 / t
         # analytic TPU throughput at this P
-        req_t = Requirements(design_point=3, platform="tpu",
+        req_t = Requirements(design_point=3, platform="tpu", device_kind=V5E,
                              precision_policy="fp", n_hits=cfg.n_hits,
                              max_p=p, target_throughput=1e12)
         gt = parallelize(g0, req_t)

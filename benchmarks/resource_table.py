@@ -15,6 +15,7 @@ from repro.core import caloclusternet as ccn
 from repro.core.passes.parallelize import Requirements
 from repro.core.pipeline import deploy
 from repro.data.belle2 import Belle2Config, generate
+from repro.launch.mesh import V5E
 
 
 def run():
@@ -30,6 +31,7 @@ def run():
         calib = {"hits": data["feats"], "mask": data["mask"]}
         for dp in (1, 2, 3):
             req = Requirements(design_point=dp, platform="tpu",
+                               device_kind=V5E,
                                precision_policy="mixed",
                                n_hits=cfg.n_hits, target_throughput=3e6,
                                max_latency_s=10e-6)

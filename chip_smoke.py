@@ -18,21 +18,28 @@ generator.
 (c) ``deploy(ragged=True)`` behind ``ShardedTriggerService(ragged=...)``;
 (d) ``serve --model gatedgcn ccn``, two routes behind one releaser.
 
-Every served pipeline must have the ``pallas`` kernel backend and a
-compiled program that holds Mosaic kernels (``tpu_custom_call``). For
-(a)-(c) the served outputs are compared, event by event, with the same
-deployment built with ``kernel_backend="xla"`` (the jnp reference) on
-the same chip: trigger decisions must be equal, per-hit heads within
-the bf16 tolerance of ``tests/_numerics.py`` (a TPU's default f32
-matmul rounds its inputs to bf16), up to hits whose k-NN selection
-flips at a near-tie. Each phase prints one line with the device, the
+(a)-(c) serve weights after a few seeded ``--train-steps`` (12 mixed,
+10 fp), under which events fire, so decision equality tests
+something. Every served pipeline must have the ``pallas`` kernel
+backend and a compiled program that holds Mosaic kernels
+(``tpu_custom_call``); the kernel count comes from the same
+deployment built again through ``serve.ccn_deployment``. Release must
+be exactly-once and in submission order. For (a)-(c) the served
+outputs are compared, event by event, with that deployment built with
+``kernel_backend="xla"`` (the jnp reference) on the same chip and run
+at ``jax.default_matmul_precision("highest")``: trigger decisions
+exactly, every per-hit head element within the f32 tolerance of
+``tests/_numerics.py``. The same reference at the default precision,
+which rounds f32 matmul inputs to bf16, is the control: it must miss
+that tolerance, so a kernel that computed in bf16 would fail. Each phase prints one line with the device, the
 backend, and the seconds JAX spent compiling (persistent-cache hits
 included, and counted).
 
-``--four-chips`` runs only the CCN service with four replicas, one per
-chip, and the same traffic through one replica: each replica's outputs
-must live on its own device, release must be exactly-once and in
-submission order, and the outputs must equal the one-replica run's.
+``--four-chips`` runs only ``serve --replicas 4`` on four chips and
+the same traffic with ``--replicas 1``: each replica must have served
+events with every output on its own device, release must be
+exactly-once and in submission order, and the outputs must equal the
+one-replica run's bit for bit.
 
 The last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -53,6 +60,11 @@ ROOT = pathlib.Path(__file__).resolve().parent
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
 EXPECT_BACKEND = "pallas"
 N_EVENTS = 256
+# seeded condensation training under which events fire, so the
+# decision check is not empty: the mixed path fires from 12 steps on
+# (all events), the fp path at 10 (most, not all)
+TRAIN_MIXED = ["--train-steps", "12"]
+TRAIN_FP = ["--train-steps", "10"]
 
 
 def _check(cond, msg):
@@ -102,15 +114,26 @@ def count_kernels(pipe, feeds) -> int:
 
 
 def _check_pallas(name, pipe, feeds) -> int:
-    """The served pipeline runs the Pallas backend, and its compiled
-    launch of ``feeds`` (one micro-batch) holds Mosaic kernels;
-    returns how many."""
+    """The pipeline runs the Pallas backend, and its compiled launch of
+    ``feeds`` (one micro-batch) holds Mosaic kernels; returns how
+    many."""
     _check(pipe.backend == EXPECT_BACKEND,
-           f"{name}: served backend {pipe.backend!r}, expected "
+           f"{name}: backend {pipe.backend!r}, expected "
            f"{EXPECT_BACKEND!r}")
     n = count_kernels(pipe, feeds)
     _check(n > 0, f"{name}: no tpu_custom_call in the compiled program")
     return n
+
+
+def _check_served(name, s, n_events):
+    """A ``serve.main`` summary: every event released once, none
+    failed, submission order kept, Pallas kernels served."""
+    _check(s["released"] == n_events and s["failed"] == 0,
+           f"{name}: released {s['released']} of {n_events}, failed "
+           f"{s['failed']}")
+    _check(s["in_order"], f"{name}: release broke submission order")
+    _check(s["backend"] == EXPECT_BACKEND,
+           f"{name}: served backend {s['backend']!r}")
 
 
 def _pad_rows(a, width):
@@ -122,28 +145,62 @@ def _pad_rows(a, width):
     return np.pad(a, pw)
 
 
-def compare(name, served, want) -> dict:
-    """Served per-event outputs against the reference's batched ones:
-    trigger decisions exactly, per-hit heads within bf16 tolerance up
-    to near-tie selection flips."""
+def references(ref, feeds):
+    """The xla deployment ``ref`` on ``feeds`` at full f32 matmul
+    precision, and at the default (bf16-input) precision as the
+    control. One deployment serves both, so they share its calibrated
+    int8 scales; only the jitted launches see the precision."""
+    import jax
+    lo = ref(feeds)
+    with jax.default_matmul_precision("highest"):
+        hi = ref(feeds)
+    return hi, lo
+
+
+def compare(name, served, hi, lo) -> dict:
+    """Served per-event outputs and the bf16 control against the f32
+    reference's batched ones: differing trigger decisions, and per-hit
+    head elements beyond the f32 tolerance, counted for ``_judge``."""
     import numpy as np
-    from _numerics import assert_selection_close
-    _check(all(o is not None for o in served), f"{name}: failed events")
+    from _numerics import tolerance
+    rtol, atol = tolerance("float32")
     got_t = np.array([bool(o["cps"]["trigger"]) for o in served])
-    want_t = np.asarray(want["cps"]["trigger"]).astype(bool)
-    flips = int((got_t != want_t).sum())
-    _check(flips == 0, f"{name}: {flips}/{got_t.size} trigger decisions "
-                       f"differ from the xla reference")
-    worst = 0.0
-    for head in sorted(k for k in want if k != "cps"):
-        w = np.asarray(want[head], np.float64)
+    want_t = np.asarray(hi["cps"]["trigger"]).astype(bool)
+    res = {"events": int(got_t.size), "triggers": int(want_t.sum()),
+           "decisions_differ": int((got_t != want_t).sum())}
+    sound = ctrl = 0.0
+    over = ctrl_over = total = 0
+    for head in sorted(k for k in hi if k != "cps"):
+        w = np.asarray(hi[head], np.float64)
         g = np.stack([_pad_rows(o[head], w.shape[1])
                       for o in served]).astype(np.float64)
-        assert_selection_close(g, w, dtype="bfloat16",
-                               context=f"{name}/{head}")
-        worst = max(worst, float(np.max(np.abs(g - w))))
-    return {"events": int(got_t.size), "triggers": int(got_t.sum()),
-            "max_abs_diff": worst}
+        c = np.asarray(lo[head], np.float64)
+        _check(np.isfinite(g).all(), f"{name}/{head}: non-finite output")
+        tol = atol + rtol * np.abs(w)
+        sound = max(sound, float(np.max(np.abs(g - w))))
+        ctrl = max(ctrl, float(np.max(np.abs(c - w))))
+        over += int(np.sum(np.abs(g - w) > tol))
+        ctrl_over += int(np.sum(np.abs(c - w) > tol))
+        total += w.size
+    res.update(max_diff=sound, beyond_f32_tol=over,
+               bf16_control_diff=ctrl, control_beyond_f32_tol=ctrl_over,
+               head_elements=total)
+    return res
+
+
+def _judge(name, res):
+    _check(res["decisions_differ"] == 0,
+           f"{name}: {res['decisions_differ']}/{res['events']} trigger "
+           f"decisions differ from the f32 xla reference")
+    _check(res["triggers"] > 0,
+           f"{name}: no event fires; the decision check would be empty")
+    _check(res["beyond_f32_tol"] == 0,
+           f"{name}: {res['beyond_f32_tol']}/{res['head_elements']} head "
+           f"elements beyond f32 tolerance of the reference (max "
+           f"{res['max_diff']:.3e})")
+    _check(res["control_beyond_f32_tol"] > 0,
+           f"{name}: the bf16 control is within f32 tolerance; the check "
+           f"would not catch a bf16 kernel")
 
 
 def _line(phase, what, dev, backend, **fields):
@@ -154,36 +211,49 @@ def _line(phase, what, dev, backend, **fields):
 
 # -------------------------------------------------------------- phases ----
 def phase_serve(serve, meter, dev, phase, argv, what):
-    """(a)/(b): one ``serve.main`` run, then the same deployment with
-    the xla kernels on the same events."""
+    """(a)/(b): one ``serve.main`` run, then the same deployment built
+    again for its kernel count and with the xla kernels as the
+    reference, on the same events."""
+    from repro.core.pipeline import _cut_hits, deploy, deploy_bucketed
+    argv = argv + ["--events", str(N_EVENTS)]
     m0 = meter.mark()
-    s = serve.main(argv + ["--train-steps", "0", "--events",
-                           str(N_EVENTS)])
+    s = serve.main(argv)
     served_cost = meter.since(m0)
-    _check(s["released"] == N_EVENTS and s["failed"] == 0,
-           f"{phase}: released {s['released']}, failed {s['failed']}")
-    from repro.core.pipeline import _cut_hits
-    sv = s["servables"][0]
-    pipes = (sv.pipe.pipes if hasattr(sv.pipe, "pipes")     # bucketed
-             else {sv.pipe.req.n_hits: sv.pipe})
+    _check_served(phase, s, N_EVENTS)
+    args = serve.build_parser().parse_args(argv)
+    m = serve.ccn_deployment(args, serve.ccn_params(args))
+    events = m["events"](N_EVENTS, 7)        # serve.main's own events
+
+    def build(**kw):
+        if args.buckets:
+            return deploy_bucketed(
+                m["graph"], m["req"], buckets=args.buckets,
+                microbatch=args.bucket_microbatch,
+                calibration_feeds=m["calibration_feeds"], **kw)
+        return deploy(m["graph"], m["req"],
+                      calibration_feeds=m["calibration_feeds"], **kw)
+
+    built = build()
+    pipes = (built.pipes if args.buckets else {built.req.n_hits: built})
     kernels = sum(_check_pallas(
-        f"{phase}/{b}", p, _cut_hits(_stack(s["events"], p.microbatch), b))
+        f"{phase}/{b}", p, _cut_hits(_stack(events, p.microbatch), b))
         for b, p in pipes.items())
     m1 = meter.mark()
-    ref = s["redeploy"](kernel_backend="xla")
-    want = ref(_stack(s["events"]))
-    cmp_ = compare(phase, s["outputs"], want)
+    res = compare(phase, s["outputs"], *references(build(
+        kernel_backend="xla"), _stack(events)))
     _line(phase, what, dev, s["backend"], kernels=kernels,
           **served_cost, ref_compile_s=meter.since(m1)["compile_s"],
-          **cmp_)
+          **res)
+    _judge(phase, res)
 
 
 def phase_ragged(serve, meter, dev):
     """(c): the padding-free executable behind the ragged service."""
     from repro.core.pipeline import deploy
     from repro.serving import ShardedTriggerService
-    args = serve.build_parser().parse_args(["--precision", "fp"])
-    m = serve.ccn_deployment(args)
+    args = serve.build_parser().parse_args(["--precision", "fp"]
+                                           + TRAIN_FP)
+    m = serve.ccn_deployment(args, serve.ccn_params(args))
     events = m["events"](N_EVENTS, 7)
     m0 = meter.mark()
     rp = deploy(m["graph"], m["req"], batch=8, ragged=True,
@@ -201,30 +271,34 @@ def phase_ragged(serve, meter, dev):
         bins, segids=bins["mask"].astype("int32"),
         slots=bins["mask"].astype("int32")))
     m1 = meter.mark()
-    ref = deploy(m["graph"], m["req"], batch=8, ragged=True,
-                 calibration_feeds=m["calibration_feeds"],
-                 kernel_backend="xla")
-    cmp_ = compare("c", served, ref(_stack(events)))
+    res = compare("c", served, *references(deploy(
+        m["graph"], m["req"], batch=8, ragged=True,
+        calibration_feeds=m["calibration_feeds"], kernel_backend="xla"),
+        _stack(events)))
     _line("c", "deploy(ragged=True) + ShardedTriggerService(ragged=)",
           dev, rp.backend, kernels=kernels, **served_cost,
-          ref_compile_s=meter.since(m1)["compile_s"], **cmp_)
+          ref_compile_s=meter.since(m1)["compile_s"], **res)
+    _judge("c", res)
 
 
 def phase_routes(serve, meter, dev):
     """(d): GatedGCN and CCN routes side by side."""
+    argv = ["--model", "gatedgcn", "ccn", "--train-steps", "0",
+            "--events", str(N_EVENTS // 2)]
     m0 = meter.mark()
-    s = serve.main(["--model", "gatedgcn", "ccn", "--train-steps", "0",
-                    "--events", str(N_EVENTS // 2)])
+    s = serve.main(argv)
     cost = meter.since(m0)
-    _check(s["released"] == N_EVENTS // 2 and s["failed"] == 0,
-           f"d: released {s['released']}, failed {s['failed']}")
+    _check_served("d", s, N_EVENTS // 2)
     for row in s["routes"]:
         _check(row["completed"] == row["submitted"] > 0,
                f"d: route {row['route']} completed {row['completed']} of "
                f"{row['submitted']}")
-    kernels = sum(_check_pallas(f"d/{sv.name}", sv.pipe,
-                                _stack(sv.events(sv.pipe.microbatch, 0)))
-                  for sv in s["servables"])
+    args = serve.build_parser().parse_args(argv)
+    kernels = 0
+    for name in args.model:
+        sv = serve.MODELS[name](args)
+        kernels += _check_pallas(f"d/{name}", sv.pipe,
+                                 _stack(sv.events(sv.pipe.microbatch, 0)))
     _line("d", "serve --model gatedgcn ccn", dev, s["backend"],
           kernels=kernels, **cost, events=s["released"],
           routes=",".join(f"{r['route']}:{r['completed']}"
@@ -232,80 +306,53 @@ def phase_routes(serve, meter, dev):
 
 
 def phase_four_chips(serve, meter, dev):
-    """The CCN service on four replicas, one per chip, against one
-    replica on the same traffic."""
+    """``serve --replicas 4``, one replica per chip, against
+    ``--replicas 1`` on the same traffic."""
     import jax
     import numpy as np
-
-    from repro.core.pipeline import deploy
-    from repro.serving import ShardedTriggerService
     devs = jax.devices()
     _check(len(devs) == 4, f"four chips expected, found {len(devs)}")
-    args = serve.build_parser().parse_args(["--precision", "mixed"])
-    m = serve.ccn_deployment(args)
-    pipe = deploy(m["graph"], m["req"],
-                  calibration_feeds=m["calibration_feeds"])
-    _check(pipe.backend == EXPECT_BACKEND, f"backend {pipe.backend!r}")
-    events = m["events"](N_EVENTS, 7)
-
-    def run(n_replicas):
-        seen = [set() for _ in range(n_replicas)]
-
-        def infer_on(i):
-            def infer(batch):
-                out = pipe({"hits": batch["hits"], "mask": batch["mask"]})
-                seen[i].update(d for leaf in jax.tree_util.tree_leaves(out)
-                               for d in leaf.devices())
-                return out
-            return infer
-
-        svc = ShardedTriggerService(
-            [infer_on(i) for i in range(n_replicas)],
-            n_replicas=n_replicas, microbatch=max(pipe.microbatch, 16),
-            window_s=2e-3, loop="streaming")
-        released = []
-        try:
-            futs = []
-            for i, ev in enumerate(events):
-                f = svc.submit(ev)
-                f.add_done_callback(lambda _f, i=i: released.append(i))
-                futs.append(f)
-            outs = [f.result(timeout=120) for f in futs]
-            svc.drain()
-            stats = svc.stats.summary()["per_replica"]
-            placed = [r.device for r in svc.replicas]
-        finally:
-            svc.close()
-        return outs, seen, released, stats, placed
-
+    argv = ["--precision", "mixed", "--train-steps", "0",
+            "--events", str(N_EVENTS)]
     m0 = meter.mark()
-    outs4, seen, released, stats, placed = run(4)
+    s4 = serve.main(argv + ["--replicas", "4"])
     cost = meter.since(m0)
-    _check(placed == devs, f"replicas placed on {placed}")
-    for i, d in enumerate(devs):
-        _check(stats[i]["completed"] > 0, f"replica {i} served nothing")
-        _check(seen[i] == {d}, f"replica {i} outputs on {seen[i]}, "
-                               f"expected {{{d}}}")
-    _check(released == list(range(N_EVENTS)),
-           "release was not exactly-once in submission order")
-    _check(sum(r["completed"] for r in stats) == N_EVENTS
-           and all(r["failed"] == 0 for r in stats),
-           f"completions {[r['completed'] for r in stats]}")
-    outs1, _, released1, _, _ = run(1)
-    _check(released1 == list(range(N_EVENTS)), "one-replica release order")
-    diff = 0
-    for a, b in zip(outs4, outs1):
-        la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
-        diff += sum(not np.array_equal(x, y) for x, y in zip(la, lb))
+    _check_served("four-chip", s4, N_EVENTS)
+    rows = s4["per_replica"]
+    _check([r["devices"] for r in rows] == [[str(d)] for d in devs],
+           f"replica output devices {[r['devices'] for r in rows]}, "
+           f"expected one each of {[str(d) for d in devs]}")
+    _check(all(r["completed"] > 0 for r in rows)
+           and sum(r["completed"] for r in rows) == N_EVENTS,
+           f"per-replica completions {[r['completed'] for r in rows]}")
+    s1 = serve.main(argv + ["--replicas", "1"])
+    _check_served("one-replica", s1, N_EVENTS)
+    diff = sum(not np.array_equal(x, y)
+               for a, b in zip(s4["outputs"], s1["outputs"])
+               for x, y in zip(jax.tree_util.tree_leaves(a),
+                               jax.tree_util.tree_leaves(b)))
     _check(diff == 0, f"{diff} output arrays differ from the one-replica "
                       f"run")
     print(f"[smoke] four-chip: device={dev['platform']} "
-          f"kind={dev['kind']!r} count={len(devs)} backend={pipe.backend} "
+          f"kind={dev['kind']!r} count={len(devs)} backend={s4['backend']} "
           f"replicas=4 per_replica_events="
-          f"{[r['completed'] for r in stats]} "
-          f"output_devices={[sorted(str(d) for d in s) for s in seen]} "
+          f"{[r['completed'] for r in rows]} "
+          f"output_devices={[r['devices'] for r in rows]} "
           f"exactly_once_in_order=True equal_to_one_replica=True "
           f"{' '.join(f'{k}={v}' for k, v in cost.items())}", flush=True)
+
+
+ONE_CHIP_PHASES = {
+    "a": lambda s, m, d: phase_serve(
+        s, m, d, "a", ["--precision", "mixed"] + TRAIN_MIXED,
+        "serve ccn upgrade --precision mixed (per-event)"),
+    "b": lambda s, m, d: phase_serve(
+        s, m, d, "b", ["--precision", "fp", "--buckets", "32", "64", "128",
+                       "--bucket-microbatch", "8"] + TRAIN_FP,
+        "serve ccn upgrade --precision fp --buckets 32 64 128"),
+    "c": phase_ragged,
+    "d": phase_routes,
+}
 
 
 # ---------------------------------------------------------------- main ----
@@ -331,22 +378,10 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     meter = CompileMeter()
-    if args.four_chips:
-        phases = [("four-chip", phase_four_chips)]
-    else:
-        phases = [
-            ("a", lambda s, m, d: phase_serve(
-                s, m, d, "a", ["--precision", "mixed"],
-                "serve ccn upgrade --precision mixed (per-event)")),
-            ("b", lambda s, m, d: phase_serve(
-                s, m, d, "b", ["--precision", "fp", "--buckets", "32",
-                               "64", "128", "--bucket-microbatch", "8"],
-                "serve ccn upgrade --precision fp --buckets 32 64 128")),
-            ("c", phase_ragged),
-            ("d", phase_routes),
-        ]
+    phases = ({"four-chip": phase_four_chips} if args.four_chips
+              else ONE_CHIP_PHASES)
     failed = []
-    for name, fn in phases:
+    for name, fn in phases.items():
         try:
             fn(serve, meter, dev)
         except (Exception, SystemExit) as e:   # noqa: BLE001 — report,

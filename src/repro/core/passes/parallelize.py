@@ -40,8 +40,8 @@ class Requirements:
     target_throughput: float = 1.0e6     # events / s / replica-group
     max_latency_s: float | None = None   # trigger budget (paper: 10 µs)
     platform: str = "tpu"                # 'tpu' | 'cpu'
-    # jax.Device.device_kind: picks the chip's peaks on 'tpu'
-    device_kind: str | None = hw.V5E
+    # jax.Device.device_kind: picks the chip's peaks; required on 'tpu'
+    device_kind: str | None = None
     design_point: int = 3                # ① ② ③
     n_hits: int = 128                    # graph size per event
     precision_policy: str = "mixed"      # 'fp' | 'mixed' (paper: 16b/8b)
@@ -63,7 +63,7 @@ def _mxu_efficiency(op, n_rows: int, n_hits: int = 128) -> float:
 
 
 def segment_time(ops, n_hits: int, p: int, platform: str = "tpu",
-                 device_kind: str | None = hw.V5E) -> float:
+                 device_kind: str | None = None) -> float:
     """Seconds for one segment step processing p events."""
     if platform == "tpu":
         peaks = hw.chip_peaks(device_kind)

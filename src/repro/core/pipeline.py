@@ -607,9 +607,9 @@ class CompiledPipeline:
     def resource_report(self):
         """Table-I analogue: per-segment FLOPs/bytes/VMEM occupancy."""
         n = self.req.n_hits
-        # occupancy against the target chip's VMEM (v5e off-TPU)
-        vmem_bytes = hw.chip_peaks(self.req.device_kind
-                                   or hw.V5E).vmem_bytes
+        # occupancy against the target chip's VMEM; a CPU has none
+        vmem_bytes = (hw.chip_peaks(self.req.device_kind).vmem_bytes
+                      if self.req.platform == "tpu" else None)
         rows = []
         for seg in self.segments:
             ops_ = [self.graph[o] for o in seg["ops"]]
@@ -626,7 +626,7 @@ class CompiledPipeline:
                 "ops": len(ops_), "flops_per_event": fl,
                 "act_bytes_per_event": by, "weight_bytes": wb,
                 "vmem_working_set": vmem,
-                "vmem_util": vmem / vmem_bytes,
+                "vmem_util": vmem / vmem_bytes if vmem_bytes else None,
                 "time_s_per_step": segment_time(ops_, n, p,
                                                 self.req.platform,
                                                 self.req.device_kind),

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu
+
 
 def _edge_aggregate_cell(msgs, dst, maskv, i, *, bm, be, reduce, out_dtype):
     """One destination-row block: msgs:(E,d) against dst/maskv:(E,);
@@ -55,8 +57,7 @@ def _edge_aggregate_cell(msgs, dst, maskv, i, *, bm, be, reduce, out_dtype):
         kc = maskv[c * be:(c + 1) * be]
         onehot = ((rows == dc[None, :]).astype(jnp.float32)
                   * kc[None, :])                          # (bm, be)
-        acc = acc + jnp.dot(onehot, mc,
-                            preferred_element_type=jnp.float32)
+        acc = acc + mxu.dot(onehot, mc)
         if reduce == "mean":
             cnt = cnt + jnp.sum(onehot, axis=1)
     if reduce == "mean":

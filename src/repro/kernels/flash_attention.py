@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   causal, nk, bq, bk, scale, out_dtype):
@@ -44,6 +46,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         k = k_ref[0].astype(jnp.float32)             # (bk, d)
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=mxu.precision(q, k),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                 # (bq, bk)
         if causal:
@@ -59,7 +62,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
                                                   keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=mxu.precision(p, v),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 
 def _activate(y, activation: str | None):
     if activation in (None, "none", "linear"):
@@ -51,9 +53,7 @@ def _looped_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, activation, nk,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += mxu.dot(x_ref[...], w_ref[...])
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -64,7 +64,7 @@ def _looped_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, activation, nk,
 
 
 def _flattened_kernel(x_ref, w_ref, b_ref, o_ref, *, activation, out_dtype):
-    y = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    y = mxu.dot(x_ref[...], w_ref[...])
     if b_ref is not None:
         y = y + b_ref[...].astype(jnp.float32)
     o_ref[...] = _activate(y, activation).astype(out_dtype)
@@ -74,7 +74,7 @@ def _flattened_kernel_batched(x_ref, w_ref, b_ref, o_ref, *, activation,
                               out_dtype):
     # leading block dim 1 = one event per grid cell; weights/bias are
     # shared across the event grid (their BlockSpecs ignore the index)
-    y = jnp.dot(x_ref[0], w_ref[...], preferred_element_type=jnp.float32)
+    y = mxu.dot(x_ref[0], w_ref[...])
     if b_ref is not None:
         y = y + b_ref[...].astype(jnp.float32)
     o_ref[0] = _activate(y, activation).astype(out_dtype)

@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu
+
 
 def _gravnet_cell(si, sj, fj, maskj, i, *, k, scale, bm, out_dtype):
     """One row-block of one event: si:(bm,ds) against sj:(n,ds)/fj:(n,df)
@@ -55,7 +57,7 @@ def _gravnet_cell(si, sj, fj, maskj, i, *, k, scale, bm, out_dtype):
     # Pairwise squared distances for this row block: (bm, n).
     d2 = (jnp.sum(si * si, axis=1, keepdims=True)
           + jnp.sum(sj * sj, axis=1)[None, :]
-          - 2.0 * jnp.dot(si, sj.T, preferred_element_type=jnp.float32))
+          - 2.0 * mxu.dot(si, sj.T))
     col = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 0) + i * bm
     invalid = (maskj[None, :] <= 0) | (col == row)   # exclude self + padding
@@ -70,7 +72,7 @@ def _gravnet_cell(si, sj, fj, maskj, i, *, k, scale, bm, out_dtype):
         dmin = jnp.min(d2, axis=1)                          # (bm,)
         amin = jnp.argmin(d2, axis=1).astype(jnp.int32)     # (bm,)
         onehot = (col == amin[:, None]).astype(jnp.float32)  # (bm, n)
-        fsel = jnp.dot(onehot, fj, preferred_element_type=jnp.float32)
+        fsel = mxu.dot(onehot, fj)
         valid = dmin < big * 0.5
         w = jnp.where(valid, jnp.exp(-scale * dmin), 0.0)    # (bm,)
         wf = w[:, None] * fsel

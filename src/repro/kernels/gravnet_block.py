@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu
 from repro.kernels.fused_dense import _activate
 from repro.kernels.gravnet import _gravnet_cell
 
@@ -95,9 +96,8 @@ def _epilogue_dense(h, wo, bo, *, bn, bk, activation, out_dtype):
     cols = []
     for j0 in range(0, dout, bn):
         j1 = min(j0 + bn, dout)
-        parts = [jnp.dot(h[:, k0:min(k0 + bk, dcat)],
-                         wo[k0:min(k0 + bk, dcat), j0:j1],
-                         preferred_element_type=jnp.float32)
+        parts = [mxu.dot(h[:, k0:min(k0 + bk, dcat)],
+                         wo[k0:min(k0 + bk, dcat), j0:j1])
                  for k0 in range(0, dcat, bk)]
         acc = parts[0]
         for p in parts[1:]:
@@ -115,14 +115,11 @@ def _gravnet_block_cell(xi, xall, maskj, ws, bs, wf, bf, wo, bo, i, *, k,
     xi:(bm,dh) query rows, xall:(n,dh) all rows, maskj:(n,) validity;
     ``i`` is the row-block index within the event. All arithmetic f32.
     """
-    s_all = (jnp.dot(xall, ws, preferred_element_type=jnp.float32)
-             + bs.astype(jnp.float32))
-    f_all = (jnp.dot(xall, wf, preferred_element_type=jnp.float32)
-             + bf.astype(jnp.float32))
+    s_all = mxu.dot(xall, ws) + bs.astype(jnp.float32)
+    f_all = mxu.dot(xall, wf) + bf.astype(jnp.float32)
     # the query rows' coordinates: recomputed from the row block (f32
     # matmul rows are independent, so this equals s_all's rows bitwise)
-    si = (jnp.dot(xi, ws, preferred_element_type=jnp.float32)
-          + bs.astype(jnp.float32))
+    si = mxu.dot(xi, ws) + bs.astype(jnp.float32)
     agg = _gravnet_cell(si, s_all, f_all, maskj, i, k=k, scale=scale,
                         bm=bm, out_dtype=jnp.float32)
     h = jnp.concatenate([xi, agg], axis=1) if concat_x else agg

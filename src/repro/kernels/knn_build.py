@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu
+
 
 def _knn_select_cell(si, sj, segi, segj, i, *, k, bm):
     """One row-block of neighbor selection: si:(bm,ds) rows against
@@ -56,7 +58,7 @@ def _knn_select_cell(si, sj, segi, segj, i, *, k, bm):
     n = sj.shape[0]
     d2 = (jnp.sum(si * si, axis=1, keepdims=True)
           + jnp.sum(sj * sj, axis=1)[None, :]
-          - 2.0 * jnp.dot(si, sj.T, preferred_element_type=jnp.float32))
+          - 2.0 * mxu.dot(si, sj.T))
     col = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 0) + i * bm
     # same-event candidates only; exclude self and padding (segid < 0)
@@ -102,7 +104,7 @@ def _knn_agg_cell(fj, idx, d2, *, k, scale, bm, out_dtype):
         amin = jnp.sum(jnp.where(sel, idx, 0), axis=1)       # (bm,)
         dmin = jnp.sum(jnp.where(sel, d2, 0.0), axis=1)      # (bm,)
         onehot = (col == amin[:, None]).astype(jnp.float32)  # (bm, n)
-        fsel = jnp.dot(onehot, fj, preferred_element_type=jnp.float32)
+        fsel = mxu.dot(onehot, fj)
         valid = dmin < big * 0.5
         w = jnp.where(valid, jnp.exp(-scale * dmin), 0.0)
         wf = w[:, None] * fsel

@@ -92,7 +92,8 @@ def affine_extrapolate(t2: dict, t4: dict, l_full: int) -> dict:
     return out
 
 
-def roofline(terms: dict, *, n_chips: int, model_flops: float) -> dict:
+def roofline(terms: dict, *, n_chips: int, model_flops: float,
+             device_kind: str) -> dict:
     """Three-term roofline (seconds) + dominant bottleneck.
 
     FLOPs/bytes from cost_analysis are whole-program totals of the SPMD
@@ -101,7 +102,7 @@ def roofline(terms: dict, *, n_chips: int, model_flops: float) -> dict:
     totals here treat cost_analysis as PER-DEVICE work and multiply terms
     accordingly — see EXPERIMENTS.md §Methodology for validation).
     """
-    peaks = hw.chip_peaks(hw.V5E)
+    peaks = hw.chip_peaks(device_kind)
     t_compute = terms["flops"] / peaks.flops_bf16
     t_memory = terms["bytes"] / peaks.hbm_bw
     t_coll = terms["collective_bytes"] / peaks.ici_bw

@@ -14,8 +14,10 @@ import jax             # noqa: E402
 
 from repro import configs                                   # noqa: E402
 from repro.launch import analysis                           # noqa: E402
-from repro.launch.mesh import make_production_mesh          # noqa: E402
+from repro.launch.mesh import V5E, make_production_mesh     # noqa: E402
 
+# the production pod's chip, whose published peaks the roofline uses
+TARGET_KIND = V5E
 REPORT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                           "reports", "dryrun")
 
@@ -87,7 +89,8 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, cost_pass: bool,
 
     effective = rec.get("per_device_corrected", rec["per_device"])
     rec["roofline"] = analysis.roofline(effective, n_chips=n_chips,
-                                        model_flops=cell.model_flops)
+                                        model_flops=cell.model_flops,
+                                        device_kind=TARGET_KIND)
     os.makedirs(report_dir, exist_ok=True)
     with open(out_path, "w") as f:
         json.dump(rec, f, indent=1)

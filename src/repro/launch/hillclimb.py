@@ -14,7 +14,7 @@ import jax               # noqa: E402
 
 from repro import configs                                  # noqa: E402
 from repro.launch import analysis                          # noqa: E402
-from repro.launch.dryrun import _mem_dict                  # noqa: E402
+from repro.launch.dryrun import TARGET_KIND, _mem_dict     # noqa: E402
 from repro.launch.mesh import make_production_mesh         # noqa: E402
 
 REPORT_DIR = os.path.normpath(os.path.join(
@@ -40,7 +40,8 @@ def measure(cell, *, cost_cells=None, l_full=None):
             sub[2], sub[4], l_full)
     eff = rec.get("per_device_corrected", rec["per_device"])
     rec["roofline"] = analysis.roofline(eff, n_chips=mesh.devices.size,
-                                        model_flops=cell.model_flops)
+                                        model_flops=cell.model_flops,
+                                        device_kind=TARGET_KIND)
     return rec
 
 

@@ -16,7 +16,6 @@ import jax
 class ChipPeaks:
     """Published per-chip peaks of one TPU generation."""
     flops_bf16: float       # FLOP/s (bf16 MXU)
-    ops_int8: float         # OP/s (int8 MXU)
     hbm_bw: float           # B/s
     ici_bw: float           # B/s per link
     vmem_bytes: int
@@ -30,7 +29,7 @@ V5E = "TPU v5 lite"         # jax.Device.device_kind of a TPU v5e chip
 # not here is an error (``chip_peaks``), never a default.
 CHIP_PEAKS = {
     V5E: ChipPeaks(
-        flops_bf16=197e12, ops_int8=393e12, hbm_bw=819e9,
+        flops_bf16=197e12, hbm_bw=819e9,
         # 1,600 Gbit/s chip-to-chip over four links
         ici_bw=50e9,
         # VMEM is not among the published figures: the resource
@@ -41,9 +40,14 @@ CHIP_PEAKS = {
 }
 
 
-def chip_peaks(device_kind: str) -> ChipPeaks:
-    """The published peaks of a TPU ``device_kind``; raises for a kind
-    with no entry in ``CHIP_PEAKS``."""
+def chip_peaks(device_kind: str | None) -> ChipPeaks:
+    """The published peaks of a TPU ``device_kind``; raises for no
+    kind and for a kind with no entry in ``CHIP_PEAKS``."""
+    if device_kind is None:
+        raise ValueError(
+            "a TPU cost model needs the chip's device_kind "
+            "(jax.Device.device_kind, e.g. Requirements.device_kind="
+            f"{V5E!r}); none was given")
     try:
         return CHIP_PEAKS[device_kind]
     except KeyError:

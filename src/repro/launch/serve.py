@@ -10,12 +10,14 @@ the real-time sharded trigger service (micro-batching window, strict
 in-order completion, hedged dispatch), stream synthetic events through
 it, and report throughput/latency percentiles.
 
-``main(argv)`` returns a summary (events released and failed, device,
-kernel backend, the deployed servables and the per-event outputs) for
+``main(argv)`` returns a summary (events released and failed, whether
+release kept submission order, device, kernel backend, the per-event
+outputs, and each replica's completed count and output devices) for
 programmatic callers such as ``chip_smoke.py``. Without
 ``--inject-faults`` any failed event is an error: the run exits
-non-zero. The persistent compilation cache lives where
-``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
+non-zero, as it does when release breaks submission order. The
+persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
+says, else in ``<repo>/.jax_cache``.
 
 ``--model`` picks the route(s) from the serve-side model registry
 (``MODELS``; default ``ccn``). The single-model ``ccn`` selection runs
@@ -154,11 +156,53 @@ def _ccn_configs(detector: str):
     return ccn.CCNConfig(), Belle2Config()
 
 
+def ccn_params(args):
+    """The CCN route's weights: random init from seed 0, then
+    ``--train-steps`` steps of condensation training (AdamW, seeded
+    batches) so the demo's decisions are meaningful."""
+    from repro.core import caloclusternet as ccn
+    from repro.data.belle2 import generate
+    cfg, gen_cfg = _ccn_configs(args.detector)
+    params = ccn.init(jax.random.PRNGKey(0), cfg)
+    if args.train_steps <= 0:
+        return params
+    import jax.numpy as jnp
+
+    from repro.core.condensation import condensation_loss
+    from repro.optim import (AdamWConfig, adamw_init, adamw_update,
+                             cosine_warmup)
+    ocfg = AdamWConfig(weight_decay=0.01)
+    lrf = cosine_warmup(peak_lr=2e-3, warmup_steps=10,
+                        total_steps=args.train_steps)
+    opt = adamw_init(params, ocfg)
+
+    @jax.jit
+    def _step(p, o, b):
+        def lf(q):
+            out = ccn.apply(q, b["feats"], b["mask"], cfg)
+            labels = {"object_id": b["object_id"],
+                      "energy": b["energy"], "cls": b["cls"]}
+            return condensation_loss(out, labels, b["mask"],
+                                     k_max=cfg.k_max)
+        (l, m), g = jax.value_and_grad(lf, has_aux=True)(p)
+        p2, o2, _ = adamw_update(g, o, p, lr=lrf(o["step"]), cfg=ocfg)
+        return p2, o2, l
+
+    for st in range(args.train_steps):
+        raw = generate(gen_cfg, 32, seed=500 + st)
+        b = {k: jnp.asarray(v) for k, v in raw.items()
+             if k != "trigger_truth"}
+        params, opt, l = _step(params, opt, b)
+    print(f"[serve] warm-trained {args.train_steps} steps, "
+          f"loss {float(l):.3f}")
+    return params
+
+
 def ccn_deployment(args, params=None) -> dict:
     """What the CCN route deploys: the model graph (random init from
-    seed 0 unless trained ``params`` are given), its Requirements on
-    this device, the calibration feeds, and a synthetic per-event feed
-    source ``events(n, seed)``."""
+    seed 0 unless ``params`` are given, e.g. ``ccn_params(args)``), its
+    Requirements on this device, the calibration feeds, and a
+    synthetic per-event feed source ``events(n, seed)``."""
     from repro.core import caloclusternet as ccn
     from repro.data.belle2 import generate
     cfg, gen_cfg = _ccn_configs(args.detector)
@@ -286,9 +330,10 @@ def _serve_multimodel(args):
                 live.remove(name)
             else:
                 futs.append(eng.submit(ev, route=name))
-    results, failed = _collect(futs)
+    results, failed, order = _collect(futs)
     dt = time.perf_counter() - t0
     eng.drain()
+    in_order = order == list(range(len(futs)))
     released = len(results)
     s = eng.stats.summary()
     print(f"[serve] {released} events in {dt:.2f}s -> "
@@ -321,17 +366,26 @@ def _serve_multimodel(args):
     if released < args.events:
         raise SystemExit("multi-model serving released fewer events "
                          "than were submitted")
-    _fail_unless_chaos(fk, failed)
+    _fail_unless_sound(fk, failed, in_order)
     if fk["faults"] is None and any(
             row["completed"] != row["submitted"] for row in route_rows):
         raise SystemExit("multi-model serving released fewer events "
                          "than were submitted")
-    return _summary(servables, results, failed, routes=route_rows)
+    return _summary([sv.pipe for sv in servables], results, failed,
+                    in_order, s, routes=route_rows)
 
 
 def _collect(futs):
     """Wait for every future in submission order; a failed event is
-    kept as ``None`` so results stay aligned with submissions."""
+    kept as ``None`` so results stay aligned with submissions. Also
+    returns the release order: each future's done-callback appends its
+    index, and callbacks attached in index order run in release order
+    (one attached to a future already done runs at once, after every
+    earlier release). Read it after ``drain()``, which returns once
+    the last release, callbacks included, is over."""
+    order = []
+    for i, f in enumerate(futs):
+        f.add_done_callback(lambda _f, i=i: order.append(i))
     results, failed = [], 0
     for f in futs:
         try:
@@ -339,22 +393,29 @@ def _collect(futs):
         except Exception:  # noqa: BLE001 — counted, judged by caller
             results.append(None)
             failed += 1
-    return results, failed
+    return results, failed, order
 
 
-def _fail_unless_chaos(fk, failed: int):
+def _fail_unless_sound(fk, failed: int, in_order: bool):
     """Without a fault plan a failed event is a broken deployment,
-    not a statistic: exit non-zero."""
+    not a statistic, and release out of submission order is broken
+    with or without one: exit non-zero."""
     if fk["faults"] is None and failed:
         raise SystemExit(f"{failed} event(s) failed without injected "
                          "faults")
+    if not in_order:
+        raise SystemExit("events were released out of submission order")
 
 
-def _summary(servables, results, failed, **extra) -> dict:
-    backends = sorted({s.pipe.backend for s in servables})
+def _summary(pipes, results, failed, in_order, stats, **extra) -> dict:
     return {"released": len(results), "failed": failed,
-            "device": device_info(), "backend": ",".join(backends),
-            "servables": servables, "outputs": results, **extra}
+            "in_order": in_order, "device": device_info(),
+            "backend": ",".join(sorted({p.backend for p in pipes})),
+            "outputs": results,
+            "per_replica": [{k: r[k] for k in ("replica_id", "completed",
+                                               "devices")}
+                            for r in stats["per_replica"]],
+            **extra}
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -467,40 +528,9 @@ def main(argv=None) -> dict:
     if args.model != ["ccn"]:
         return _serve_multimodel(args)
 
-    from repro.core import caloclusternet as ccn
     from repro.data.belle2 import generate
     cfg, gen_cfg = _ccn_configs(args.detector)
-    params = ccn.init(jax.random.PRNGKey(0), cfg)
-    if args.train_steps > 0:   # brief condensation training so the
-        import jax.numpy as jnp    # demo's decisions are meaningful
-        from repro.core.condensation import condensation_loss
-        from repro.optim import (AdamWConfig, adamw_init, adamw_update,
-                                 cosine_warmup)
-        ocfg = AdamWConfig(weight_decay=0.01)
-        lrf = cosine_warmup(peak_lr=2e-3, warmup_steps=10,
-                            total_steps=args.train_steps)
-        opt = adamw_init(params, ocfg)
-
-        @jax.jit
-        def _step(p, o, b):
-            def lf(q):
-                out = ccn.apply(q, b["feats"], b["mask"], cfg)
-                labels = {"object_id": b["object_id"],
-                          "energy": b["energy"], "cls": b["cls"]}
-                return condensation_loss(out, labels, b["mask"],
-                                         k_max=cfg.k_max)
-            (l, m), g = jax.value_and_grad(lf, has_aux=True)(p)
-            p2, o2, _ = adamw_update(g, o, p, lr=lrf(o["step"]), cfg=ocfg)
-            return p2, o2, l
-
-        for st in range(args.train_steps):
-            raw = generate(gen_cfg, 32, seed=500 + st)
-            b = {k: jnp.asarray(v) for k, v in raw.items()
-                 if k != "trigger_truth"}
-            params, opt, l = _step(params, opt, b)
-        print(f"[serve] warm-trained {args.train_steps} steps, "
-              f"loss {float(l):.3f}")
-    model = ccn_deployment(args, params)
+    model = ccn_deployment(args, ccn_params(args))
     graph, req = model["graph"], model["req"]
     feeds = model["calibration_feeds"]
     cache = None
@@ -597,9 +627,10 @@ def main(argv=None) -> dict:
                                 "mask": events["mask"][i]},
                                truth=bool(truth[i]) if monitoring
                                else None))
-    results, failed = _collect(futs)
+    results, failed, order = _collect(futs)
     dt = time.perf_counter() - t0
     eng.drain()
+    in_order = order == list(range(len(futs)))
     s = eng.stats.summary()
     trig = np.asarray([bool(r["cps"]["trigger"]) if r is not None
                        else False for r in results])
@@ -625,7 +656,7 @@ def main(argv=None) -> dict:
                   f"{bs['submitted']} events, {bs['batches']} batches, "
                   f"{bs['padded_events']} padded")
     print(f"[serve] trigger efficiency={eff:.3f} fake rate={fake:.3f} "
-          f"in-order=True")
+          f"in-order={in_order}")
     if fk["faults"] is not None:
         _print_chaos(eng, failed)
     if monitoring:
@@ -661,12 +692,8 @@ def main(argv=None) -> dict:
     if server is not None:
         server.close()
     eng.close()
-    _fail_unless_chaos(fk, failed)
-    return _summary(
-        [Servable("ccn", served, model["events"])], results, failed,
-        events=[{"hits": events["feats"][i], "mask": events["mask"][i]}
-                for i in range(args.events)],
-        redeploy=redeploy)
+    _fail_unless_sound(fk, failed, in_order)
+    return _summary([served], results, failed, in_order, s)
 
 
 if __name__ == "__main__":

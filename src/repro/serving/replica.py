@@ -131,6 +131,9 @@ class ServingStats:
     # throughput clock: stamped by the first enqueue, not construction,
     # so a replica built long before traffic reports an honest rate.
     started_at: float | None = None
+    # the devices this lane's results were computed on
+    # (``jax.Array.devices()`` of every batch's output leaves)
+    devices: set = dataclasses.field(default_factory=set)
     lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False, compare=False)
 
@@ -150,6 +153,12 @@ class ServingStats:
             self.queue_wait_s.append(timing.queue_wait_s)
             self.dispatch_s.append(timing.dispatch_s)
             self.compute_s.append(timing.compute_s)
+
+    def record_devices(self, leaves):
+        placed = {d for leaf in leaves if hasattr(leaf, "devices")
+                  for d in leaf.devices()}
+        with self.lock:
+            self.devices |= placed
 
     def throughput_ev_s(self):
         if self.started_at is None:
@@ -187,6 +196,7 @@ class ServingStats:
             if lat else None,
             "throughput_ev_s": self.throughput_ev_s(),
             "budget": self.budget(),
+            "devices": sorted(str(d) for d in self.samples("devices")),
         }
 
 
@@ -507,6 +517,7 @@ class ReplicaEngine:
             self._health.record_success()
         import jax
         leaves, tdef = jax.tree_util.tree_flatten(out)
+        self.stats.record_devices(leaves)
         # materialize BEFORE stamping t_done: under jax async dispatch
         # the call above returns unfinished arrays, and the compute
         # budget must include the actual device time.

@@ -285,6 +285,7 @@ class StreamingReplicaEngine(ReplicaEngine):
             self._health.record_success()
         import jax
         leaves, tdef = jax.tree_util.tree_flatten(out)
+        self.stats.record_devices(leaves)
         host = self._to_host_ring(leaves)
         t_done = time.perf_counter()
         if self._monitor is not None:

@@ -23,16 +23,15 @@ boundary values — the logic lives here once, so every test states
                              quantum and a small fraction of affected
                              elements (``int8_flip_tolerance`` derives
                              the quantum from the baked scales).
-- ``assert_selection_close``: two programs that select k-NN
-                             neighbors from coordinates computed in a
-                             different order — every hit within the
-                             dtype's tolerance except the few whose
-                             selection flipped at a near-tie.
+- ``assert_selection_close``: two deployed programs that select
+                             k-NN neighbors from coordinates computed
+                             in a different order — every hit within
+                             the dtype's tolerance except a measured
+                             share whose selection flipped at a
+                             near-tie, and those within a bound.
 - ``assert_fixture_match``: a golden fixture — integer, bool and
                              index arrays exactly, float arrays by
-                             their dtype's rtol/atol (a fixture pins
-                             what the computation means, not the bits
-                             one XLA CPU build produced).
+                             their dtype's rtol/atol.
 - ``backend_sweep``        : the backends a differential test should
                              run — ``xla`` (jnp reference), the
                              interpret-mode Pallas body, and the real
@@ -102,27 +101,40 @@ def assert_close(got, want, *, dtype=None, rtol: float | None = None,
                                err_msg=context)
 
 
+#: Selection flips between the fused and unfused f32 deployments of
+#: tests/test_fusion_block.py, measured on XLA CPU at four target ISA
+#: levels (``--xla_cpu_max_isa`` SSE4_2, AVX, AVX2 and AVX-512): at most
+#: 3 of 128 hit rows (2.3%) in one launch, each within 1.2% of its
+#: head's largest magnitude. The limits allow about twice that.
+SELECTION_FLIP_FRAC = 0.05
+SELECTION_FLIP_SCALE = 0.05
+
+
 def assert_selection_close(got, want, *, dtype="float32",
-                           max_flip_frac: float = 0.05,
                            context: str = "") -> None:
     """Per-hit comparison for outputs downstream of k-NN selection.
     Coordinates whose last bits differ can reorder two candidates at a
     near-tie, which changes that hit's neighbor set (and its
     neighbors' aggregates). Rows (the last axis is features) must agree
-    within ``dtype``'s tolerance except at most ``max_flip_frac`` of
-    them; the rest must still be finite."""
+    within ``dtype``'s tolerance except at most ``SELECTION_FLIP_FRAC``
+    of them, and every row must stay within ``SELECTION_FLIP_SCALE``
+    times the largest ``|want|``."""
     g, w = _as64(got), _as64(want)
     assert g.shape == w.shape, (context, g.shape, w.shape)
     rtol, atol = tolerance(dtype)
-    bad = np.abs(g - w) > atol + rtol * np.abs(w)
-    rows = bad.reshape(-1, w.shape[-1]).any(axis=1)
+    d = np.abs(g - w)
+    rows = (d > atol + rtol * np.abs(w)).reshape(-1, w.shape[-1]).any(1)
     frac = float(rows.mean()) if rows.size else 0.0
     tag = f" ({context})" if context else ""
-    assert np.isfinite(g).all(), f"non-finite output{tag}"
-    if frac > max_flip_frac:
+    if frac > SELECTION_FLIP_FRAC:
         raise AssertionError(
             f"selection mismatch{tag}: {frac:.1%} of hits differ beyond "
-            f"f32 tolerance (> {max_flip_frac:.1%} allowed)")
+            f"{dtype} tolerance (> {SELECTION_FLIP_FRAC:.1%} allowed)")
+    bound = SELECTION_FLIP_SCALE * float(np.abs(w).max(initial=0.0))
+    if not d.max(initial=0.0) <= bound + atol:     # NaN fails too
+        raise AssertionError(
+            f"selection mismatch{tag}: max|diff|={d.max():.3e} exceeds "
+            f"the flipped-hit bound {bound:.3e}")
 
 
 def assert_fixture_match(got: dict, want: dict, *, dtype=None,

@@ -15,6 +15,7 @@ from repro.core.passes.partition import segments
 from repro.core.pipeline import deploy
 from repro.core.quantization import (apply_precision_policy, fake_quant,
                                      quantize_weight)
+from repro.launch.mesh import V5E
 
 CFG = ccn.CCNConfig(n_hits=32)
 
@@ -142,7 +143,7 @@ def test_parallelize_meets_reachable_target(setup):
     g = map_templates(apply_precision_policy(
         partition(fuse(ccn.to_graph(params, CFG))), policy="fp"))
     req = Requirements(target_throughput=1e5, platform="tpu",
-                       n_hits=CFG.n_hits)
+                       device_kind=V5E, n_hits=CFG.n_hits)
     gp = parallelize(g, req)
     meta = gp.meta["parallelization"]
     assert meta["model_throughput_ev_s"] >= req.target_throughput
@@ -150,7 +151,8 @@ def test_parallelize_meets_reachable_target(setup):
     # smallest-P property: halving the chosen P must miss the target
     if meta["P_mxu"] > 1 and meta["P_xla"] > 1:
         req2 = Requirements(target_throughput=1e5, platform="tpu",
-                            n_hits=CFG.n_hits, max_p=meta["P_mxu"] // 2)
+                            device_kind=V5E, n_hits=CFG.n_hits,
+                            max_p=meta["P_mxu"] // 2)
         gp2 = parallelize(g, req2)
         m2 = gp2.meta["parallelization"]
         assert (m2["model_throughput_ev_s"] < req.target_throughput
@@ -296,6 +298,7 @@ def test_segments_with_different_p_rejoin_the_micro_batch():
     outs = {}
     for plat in ("tpu", "cpu"):
         req = Requirements(design_point=3, platform=plat, n_hits=64,
+                           device_kind=V5E if plat == "tpu" else None,
                            precision_policy="fp", target_throughput=1e5,
                            max_latency_s=2e-3)
         pipe = deploy(g, req, kernel_backend="xla")
@@ -310,21 +313,22 @@ def test_segments_with_different_p_rejoin_the_micro_batch():
 def test_tpu_cost_model_needs_a_known_device_kind(setup):
     params = setup[0]
     g = map_templates(partition(fuse(ccn.to_graph(params, CFG))))
-    req = Requirements(platform="tpu", device_kind="TPU v1 imaginary",
-                       n_hits=CFG.n_hits)
-    with pytest.raises(ValueError, match="TPU v1 imaginary"):
-        parallelize(g, req)
-    # the CPU platform keeps its relative constants, whatever the kind
-    parallelize(g, Requirements(platform="cpu", device_kind=None,
-                                n_hits=CFG.n_hits))
+    for kind, says in (("TPU v1 imaginary", "TPU v1 imaginary"),
+                       (None, "device_kind")):
+        req = Requirements(platform="tpu", device_kind=kind,
+                           n_hits=CFG.n_hits)
+        with pytest.raises(ValueError, match=says):
+            parallelize(g, req)
+    # the CPU platform keeps its relative constants and needs no kind
+    parallelize(g, Requirements(platform="cpu", n_hits=CFG.n_hits))
 
 
 def test_deploy_refuses_reference_backend_env_on_tpu(setup, monkeypatch):
     """REPRO_BACKEND is a CPU test lever: on a TPU it must not swap the
     compiled kernels for a reference path in silence."""
     g = ccn.to_graph(setup[0], CFG)
-    req = Requirements(platform="tpu", precision_policy="fp",
-                       n_hits=CFG.n_hits)
+    req = Requirements(platform="tpu", device_kind=V5E,
+                       precision_policy="fp", n_hits=CFG.n_hits)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for env in ("xla", "pallas_interpret"):
         monkeypatch.setenv("REPRO_BACKEND", env)

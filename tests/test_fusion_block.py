@@ -4,13 +4,12 @@ attention → flash_attention executor route.
 
 The headline invariants (docs/kernels.md):
 
-- a fused f32 ``gravnet_block`` launch equals the unfused
-  dense(S)/dense(F) → gravnet_aggregate → concat → dense(out) chain
-  within f32 tolerance, for every occupancy bucket, micro-batch width,
-  and k (the two compile to different programs, whose last bits are
-  the compiler's, so a deployed comparison also allows the few hits
-  whose k-NN selection flips at a near-tie; a batched launch against
-  its own per-event loop stays bitwise);
+- a fused f32 ``gravnet_block`` launch is **bitwise-equal** to the
+  unfused dense(S)/dense(F) → gravnet_aggregate → concat → dense(out)
+  kernel chain at the same shapes; deployed with and without fusion
+  (two different compiled programs) the outputs agree within f32
+  tolerance for every occupancy bucket, micro-batch width, and k, up
+  to the few hits whose k-NN selection flips at a near-tie;
 - the quantized ``gravnet_block_int8`` launch matches the calibrated
   unfused int8 chain within **calibration tolerance** (independently
   derived requantization grids may flip boundary values by one step)
@@ -90,7 +89,7 @@ def test_gravnet_block_matches_unfused_kernel_chain_bitwise():
                               variant="flattened",
                               backend="pallas_interpret"
                               ).reshape(b, n, -1)
-    assert_close(fused, unfused, dtype="float32")
+    assert_bitwise(fused, unfused)
 
 
 def test_gravnet_block_xla_path_matches_ref():
@@ -110,7 +109,7 @@ def test_gravnet_block_bn_split_bitwise_bk_split_close():
                                      backend="pallas_interpret")
     bn = ops.gravnet_block_batched(**o, k=k, bn=8,
                                    backend="pallas_interpret")
-    assert_close(bn, base, dtype="float32", context="column split")
+    assert_bitwise(bn, base, context="column split")
     bk = ops.gravnet_block_batched(**o, k=k, bk=16,
                                    backend="pallas_interpret")
     assert_ulp_close(bk, base, max_ulp=16, context="K split")
@@ -131,7 +130,7 @@ def test_gravnet_block_row_tiling_matches_unfused_same_bm():
     want = ops.fused_dense(h.reshape(b * n, -1), o["wo"], o["bo"],
                            activation="relu", variant="flattened",
                            backend="pallas_interpret").reshape(b, n, -1)
-    assert_close(fused, want, dtype="float32")
+    assert_bitwise(fused, want)
 
 
 # ------------------------------------------ int8 kernel equivalence ----

@@ -25,8 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _numerics import (assert_bitwise, assert_close, assert_fixture_match,
-                       backend_sweep)
+from _numerics import assert_bitwise, assert_close, backend_sweep
 
 from repro.kernels import ops
 from repro.kernels.ref import knn_aggregate_ref, knn_build_ref
@@ -64,9 +63,7 @@ def test_build_matches_ref_bitwise(backend, k):
     want_idx, want_d2 = knn_build_ref(s, seg, k=k)
     idx, d2 = ops.knn_build(s, seg, k=k, backend=backend)
     assert_bitwise(idx, want_idx, context=f"{backend}/k={k}/idx")
-    # selection is exact; the distances it reports carry the f32
-    # arithmetic of whichever compiled program computed them
-    assert_close(d2, want_d2, dtype="float32", context=f"{backend}/k={k}/d2")
+    assert_bitwise(d2, want_d2, context=f"{backend}/k={k}/d2")
 
 
 @pytest.mark.parametrize("backend", backend_sweep())
@@ -174,21 +171,28 @@ def golden():
 
 
 def test_golden_fixture_is_current(golden):
-    """Regenerating reproduces the fixture: indices exactly, distances
-    and aggregates within f32 tolerance."""
-    assert_fixture_match(_generate(), golden)
+    fresh = _generate()
+    assert set(fresh) == set(golden)
+    for name, arr in fresh.items():
+        np.testing.assert_array_equal(arr, golden[name], err_msg=name)
 
 
 @pytest.mark.parametrize("backend", backend_sweep())
 def test_kernels_match_golden(backend, golden):
-    """Selection order (idx — exact) and its distances and aggregation
-    (f32 tolerance) against the committed fixture: any change to the
-    tie-break contract shows up as an index diff."""
+    """Selection order (idx — exact) and aggregation (tolerance)
+    against the committed fixture: any change to the tie-break
+    contract shows up as an index diff. The fixture's distances are the
+    reference's own, which the xla backend reproduces bitwise; a Pallas
+    body computes them in its own order (f32 tolerance)."""
     idx, d2 = ops.knn_build(jnp.asarray(golden["s"]),
                             jnp.asarray(golden["seg"]),
                             k=int(golden["k"]), backend=backend)
     assert_bitwise(idx, golden["idx"], context=f"{backend}/idx")
-    assert_close(d2, golden["d2"], dtype="float32", context=f"{backend}/d2")
+    if backend == "xla":
+        assert_bitwise(d2, golden["d2"], context=f"{backend}/d2")
+    else:
+        assert_close(d2, golden["d2"], dtype="float32",
+                     context=f"{backend}/d2")
     agg = ops.knn_aggregate(jnp.asarray(golden["f"]), idx, d2,
                             scale=10.0, backend=backend)
     assert_close(agg, golden["agg"], dtype="float32", context=backend)

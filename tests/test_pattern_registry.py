@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _numerics import assert_bitwise, assert_close, assert_selection_close
+from _numerics import assert_bitwise, assert_close
 
 from repro.core import caloclusternet as ccn
 from repro.core.graph_ir import Graph, Operator
@@ -196,7 +196,8 @@ def test_outputs_bitwise_identical(golden, fresh):
     assert set(got) == set(want)
     for name in sorted(want):
         if np.issubdtype(want[name].dtype, np.floating):
-            assert_selection_close(got[name], want[name], context=name)
+            assert_close(got[name], want[name], dtype="float32",
+                         context=name)
         else:
             assert_bitwise(got[name], want[name], context=name)
 

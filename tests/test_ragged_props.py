@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_support import given, settings, st
-from _numerics import assert_close
+from _numerics import assert_bitwise, assert_close
 
 from repro.data.ragged import (RaggedBatch, bin_pack, bins_needed,
                                group_by_segment, offsets_from_counts,
@@ -183,10 +183,8 @@ def test_packed_knn_invariant_to_event_order(backend, perm_seed):
             np.testing.assert_array_equal(
                 nslots[valid], ridx[slot][valid],
                 err_msg=f"{backend}/event{e}/slot{slot}")
-            # neighbor slots exactly; their distances carry the f32
-            # arithmetic of the program that computed them
-            assert_close(d2row, rd2[slot], dtype="float32",
-                         context=f"{backend}/event{e}/slot{slot}/d2")
+            assert_bitwise(d2row, rd2[slot],
+                           context=f"{backend}/event{e}/slot{slot}/d2")
 
 
 # --------------------------------------------------- megakernel parity ----

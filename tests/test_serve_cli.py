@@ -2,10 +2,11 @@
 its summary, names the device it ran on, exits non-zero when an event
 fails without injected faults, and places the compilation cache only
 where it is told to."""
+import jax
 import numpy as np
 import pytest
 
-from repro.core.pipeline import CompiledPipeline
+from repro.core.pipeline import CompiledPipeline, deploy
 from repro.launch import serve
 
 _SMALL = ["--detector", "current", "--precision", "fp", "--events", "24",
@@ -22,17 +23,21 @@ def _no_persistent_cache(monkeypatch):
 
 def test_main_returns_summary_with_device_and_outputs(capsys):
     s = serve.main(_SMALL)
-    assert s["released"] == 24 and s["failed"] == 0
+    assert s["released"] == 24 and s["failed"] == 0 and s["in_order"]
     assert s["device"]["platform"] == "cpu" and s["device"]["count"] >= 1
     assert s["backend"] == "xla"            # a CPU deploys the reference
-    assert [sv.name for sv in s["servables"]] == ["ccn"]
-    assert len(s["outputs"]) == len(s["events"]) == 24
+    assert s["per_replica"] == [{"replica_id": 0, "completed": 24,
+                                 "devices": [str(jax.devices()[0])]}]
+    assert len(s["outputs"]) == 24
     assert all("cps" in o for o in s["outputs"])
     # the served outputs are the deployed pipeline's own, in order
-    ref = s["redeploy"]()
-    feeds = {k: np.stack([e[k] for e in s["events"]]) for k in ("hits",
-                                                             "mask")}
-    want = ref(feeds)
+    args = serve.build_parser().parse_args(_SMALL)
+    m = serve.ccn_deployment(args, serve.ccn_params(args))
+    ref = deploy(m["graph"], m["req"],
+                 calibration_feeds=m["calibration_feeds"])
+    evs = m["events"](24, 7)                # serve.main's own events
+    want = ref({k: np.stack([e[k] for e in evs]) for k in ("hits",
+                                                           "mask")})
     got = np.stack([o["cps"]["trigger"] for o in s["outputs"]])
     np.testing.assert_array_equal(got, np.asarray(want["cps"]["trigger"]))
     out = capsys.readouterr().out
@@ -56,6 +61,13 @@ def test_failed_events_exit_nonzero_without_fault_plan(monkeypatch):
     _fail_after_warmup(monkeypatch)
     with pytest.raises(SystemExit, match="24 event"):
         serve.main(_SMALL)
+
+
+def test_release_out_of_order_exits_nonzero():
+    chaos = {"faults": object()}             # even under a fault plan
+    serve._fail_unless_sound(chaos, 3, in_order=True)
+    with pytest.raises(SystemExit, match="submission order"):
+        serve._fail_unless_sound(chaos, 0, in_order=False)
 
 
 def test_failed_events_exit_nonzero_on_multimodel_path(monkeypatch):

@@ -146,6 +146,80 @@ def test_fused_dense_default_tiles_compile(spec, d_in, d_out):
         activation="none", variant="looped", backend="pallas", **kw))
 
 
+def test_flash_attention_compiles(spec):
+    bh, s, d = 2, 256, 64
+    _assert_kernel(ops.flash_attention.lower(
+        spec((bh, s, d)), spec((bh, s, d)), spec((bh, s, d)),
+        backend="pallas"))
+
+
+# ------------------------------------------------ matmul precision ----
+def _kernel_dots(jaxpr, inside=False):
+    """Every ``dot_general`` inside a ``pallas_call`` of ``jaxpr``,
+    nested jaxprs (jit, loops, the kernel body) included."""
+    for eqn in jaxpr.eqns:
+        if inside and eqn.primitive.name == "dot_general":
+            yield eqn
+        kernel = inside or eqn.primitive.name == "pallas_call"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_dots(sub, kernel)
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+_I8 = jnp.int8
+PRECISION_CASES = {
+    "fused_dense_flattened": lambda: (ops.fused_dense, (
+        _sds((N, DH)), _sds((DH, DF)), _sds((DF,))),
+        dict(variant="flattened")),
+    "fused_dense_looped": lambda: (ops.fused_dense, (
+        _sds((B * N, DCAT)), _sds((DCAT, DH)), _sds((DH,))),
+        dict(variant="looped")),
+    "gravnet_aggregate": lambda: (ops.gravnet_aggregate, (
+        _sds((N, DS)), _sds((N, DF)), _sds((N,))), dict(k=K)),
+    "gravnet_block": lambda: (ops.gravnet_block, (
+        _sds((N, DH)), _sds((N,)), _sds((DH, DS)), _sds((DS,)),
+        _sds((DH, DF)), _sds((DF,)), _sds((DCAT, DH)), _sds((DH,))),
+        dict(k=K)),
+    "gravnet_block_int8": lambda: (ops.gravnet_block_int8, (
+        _sds((N, DH)), _sds((N,)), _sds((DH, DS), _I8), _sds((DS,)),
+        _sds((DH, DF), _I8), _sds((DF,)), _sds((DCAT, DH), _I8),
+        _sds((DH,)), _sds((DS,)), _sds((DF,)), _sds((DH,))),
+        dict(k=K, x_scale=0.02, agg_scale=0.01, h_scale=0.03)),
+    "knn_build": lambda: (ops.knn_build, (
+        _sds((N, DS)), _sds((N,), jnp.int32)), dict(k=K)),
+    "knn_aggregate": lambda: (ops.knn_aggregate, (
+        _sds((N, DF)), _sds((N, K), jnp.int32), _sds((N, K))), {}),
+    "edge_aggregate": lambda: (ops.edge_aggregate, (
+        _sds((256, 32)), _sds((2, 256), jnp.int32)), dict(n_nodes=64)),
+    "flash_attention": lambda: (ops.flash_attention, (
+        _sds((2, 256, 64)), _sds((2, 256, 64)), _sds((2, 256, 64))), {}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PRECISION_CASES))
+def test_f32_kernel_matmuls_contract_at_full_f32(name):
+    """Mosaic's default contraction rounds f32 operands to bf16; every
+    kernel matmul on f32 operands asks for ``Precision.HIGHEST``, and
+    one on int8 operands keeps the (exact) default. Traced on the CPU:
+    the precision is a property of the kernel body, not of a chip."""
+    fn, args, kw = PRECISION_CASES[name]()
+    jaxpr = jax.make_jaxpr(
+        lambda *a: fn(*a, backend="pallas", **kw))(*args).jaxpr
+    dots = list(_kernel_dots(jaxpr))
+    assert dots, f"{name}: no matmul inside a Pallas kernel"
+    for eqn in dots:
+        f32 = all(v.aval.dtype == jnp.float32 for v in eqn.invars)
+        want = ((jax.lax.Precision.HIGHEST,) * 2 if f32 else None)
+        assert eqn.params["precision"] == want, (
+            name, [v.aval for v in eqn.invars], eqn.params["precision"])
+
+
 # ------------------------------------------------- deployed pipelines ----
 def _ccn_graph():
     cfg = ccn.CCNConfig()
