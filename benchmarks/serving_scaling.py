@@ -207,7 +207,7 @@ def main():
             infer({k: np.stack([v] * mb) for k, v in e.items()})
 
     print("replicas,microbatch,events,wall_s,throughput_ev_s,"
-          "p99_us,queue_wait_us,dispatch_us,compute_us")
+          "p99_us,queue_wait_us,pack_us,pipeline_us,release_us")
     trajectory = []
     for mb in args.microbatches:
         for r in args.replicas:
@@ -221,8 +221,9 @@ def main():
             print(f"{r},{mb},{pt['events']},{pt['wall_s']:.3f},"
                   f"{pt['throughput_ev_s']:.0f},{agg['p99_us']:.0f},"
                   f"{bud['queue_wait_us_mean']:.0f},"
-                  f"{bud['dispatch_us_mean']:.0f},"
-                  f"{bud['compute_us_mean']:.0f}")
+                  f"{bud['pack_us_mean']:.0f},"
+                  f"{bud['pipeline_us_mean']:.0f},"
+                  f"{bud['release_us_mean']:.0f}")
 
     result = {"mode": args.mode, "events": args.events,
               "service_us": args.service_us if args.mode == "synthetic"

@@ -22,7 +22,9 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.core import caloclusternet as ccn
 from repro.core.graph_ir import Graph
 from repro.core.passes.fusion import fuse
@@ -69,6 +71,14 @@ class _Executor:
 
     # -- single-op execution ------------------------------------------------
     def run_op(self, op, vals, feeds, *, force_fp=False, record=None):
+        """Lower ``op`` under ``jax.named_scope(op.name)``: the device
+        operations it becomes carry the op's name in their ``op_name``
+        metadata (the HLO instruction names do not change)."""
+        with jax.named_scope(op.name):
+            return self._run_op(op, vals, feeds, force_fp=force_fp,
+                                record=record)
+
+    def _run_op(self, op, vals, feeds, *, force_fp, record):
         t = op.op_type
         prec = "fp" if force_fp else op.precision
         if t == "input":
@@ -583,24 +593,29 @@ class CompiledPipeline:
         chunks = []
         pad = (-b) % mb
         if pad:
-            feeds = jax.tree_util.tree_map(
-                lambda a: jnp.concatenate(
-                    [a, jnp.zeros((pad, *a.shape[1:]), a.dtype)]), feeds)
+            with TraceAnnotation(spans.PIPELINE_PAD):
+                feeds = jax.tree_util.tree_map(
+                    lambda a: jnp.concatenate(
+                        [a, jnp.zeros((pad, *a.shape[1:]), a.dtype)]),
+                    feeds)
         total = b + pad
         for s in range(0, total, mb):
-            chunk = jax.tree_util.tree_map(lambda a: a[s:s + mb], feeds)
-            if self._fused:
-                chunks.append(self._whole(chunk))
-            else:
-                env: dict[str, Any] = {}
-                for seg, ins, outs, fn in self._seg_fns:
-                    env.update(fn({i: env[i] for i in ins if i in env},
-                                  chunk))
-                chunks.append(env[self.graph.outputs()[0].name])
-        out = jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *chunks)
-        if pad:
-            out = jax.tree_util.tree_map(lambda a: a[:b], out)
+            with TraceAnnotation(spans.PIPELINE_CHUNK, rows=mb):
+                chunk = jax.tree_util.tree_map(lambda a: a[s:s + mb],
+                                               feeds)
+                if self._fused:
+                    chunks.append(self._whole(chunk))
+                else:
+                    env: dict[str, Any] = {}
+                    for seg, ins, outs, fn in self._seg_fns:
+                        env.update(fn({i: env[i] for i in ins
+                                       if i in env}, chunk))
+                    chunks.append(env[self.graph.outputs()[0].name])
+        with TraceAnnotation(spans.PIPELINE_CONCAT):
+            out = jax.tree_util.tree_map(
+                lambda *xs: jnp.concatenate(xs, axis=0), *chunks)
+            if pad:
+                out = jax.tree_util.tree_map(lambda a: a[:b], out)
         return out
 
     # reporting ---------------------------------------------------------------
@@ -978,38 +993,48 @@ class RaggedPipeline:
 
         from repro.data.ragged import (RaggedBatch, bin_pack, pack_events,
                                        unpack_binned)
-        if isinstance(feeds, RaggedBatch):
-            rb = feeds
-        else:
-            rb = pack_events(np.asarray(feeds["hits"]),
-                             np.asarray(feeds["mask"]))
-        counts = rb.counts()
-        offs = np.asarray(rb.offsets)
+        with TraceAnnotation(spans.RAGGED_PACK) as ann:
+            if isinstance(feeds, RaggedBatch):
+                rb = feeds
+            else:
+                rb = pack_events(np.asarray(feeds["hits"]),
+                                 np.asarray(feeds["mask"]))
+            counts = rb.counts()
+            offs = np.asarray(rb.offsets)
+            launches = []
+            for i, j in self._plan_launches(counts):
+                sub = RaggedBatch(feats=rb.feats[offs[i]:offs[j]],
+                                  offsets=offs[i:j + 1] - offs[i])
+                bp = bin_pack(sub, self.capacity, n_bins=self.batch)
+                mask = (np.asarray(bp.segids) >= 0).astype(np.float32)
+                launches.append((j - i, bp, mask))
+            ann.set_metadata(events=len(counts),
+                             bins=self.batch * len(launches),
+                             rows=int(offs[-1] - offs[0]))
         parts = []
-        for i, j in self._plan_launches(counts):
-            sub = RaggedBatch(feats=rb.feats[offs[i]:offs[j]],
-                              offsets=offs[i:j + 1] - offs[i])
-            bp = bin_pack(sub, self.capacity, n_bins=self.batch)
-            mask = (np.asarray(bp.segids) >= 0).astype(np.float32)
-            out = self.pipe({"hits": jnp.asarray(bp.feats),
-                             "mask": jnp.asarray(mask),
-                             "segids": jnp.asarray(bp.segids),
-                             "slots": jnp.asarray(bp.slots)})
-            n_ev = j - i
-            part = {}
-            for name, v in out.items():
-                if name == "cps":
-                    part[name] = {k: np.asarray(a)[:n_ev]
-                                  for k, a in v.items()}
-                else:
-                    part[name] = unpack_binned(
-                        np.asarray(v), np.asarray(bp.segids),
-                        np.asarray(bp.slots), n_ev, self.capacity)
-            parts.append(part)
+        for n_ev, bp, mask in launches:
+            with TraceAnnotation(spans.RAGGED_LAUNCH):
+                out = self.pipe({"hits": jnp.asarray(bp.feats),
+                                 "mask": jnp.asarray(mask),
+                                 "segids": jnp.asarray(bp.segids),
+                                 "slots": jnp.asarray(bp.slots)})
+            with TraceAnnotation(spans.RAGGED_FETCH):
+                out = jax.tree_util.tree_map(np.asarray, out)
+            with TraceAnnotation(spans.RAGGED_UNPACK):
+                part = {}
+                for name, v in out.items():
+                    if name == "cps":
+                        part[name] = {k: a[:n_ev] for k, a in v.items()}
+                    else:
+                        part[name] = unpack_binned(
+                            v, np.asarray(bp.segids),
+                            np.asarray(bp.slots), n_ev, self.capacity)
+                parts.append(part)
         if len(parts) == 1:
             return parts[0]
-        return jax.tree_util.tree_map(
-            lambda *xs: np.concatenate(xs, axis=0), *parts)
+        with TraceAnnotation(spans.RAGGED_UNPACK):
+            return jax.tree_util.tree_map(
+                lambda *xs: np.concatenate(xs, axis=0), *parts)
 
     # -------------------------------------------------------------- warmup --
     def warmup(self) -> int:

@@ -340,7 +340,7 @@ def _serve_multimodel(args):
           f"{released / dt:,.0f} ev/s ({_device_label()}, "
           f"{args.replicas} replica(s) per route, {args.policy}, "
           f"{args.loop} loop)")
-    print(f"[serve] latency p50={_us(s['p50_us'])} "
+    print(f"[serve] latency to release p50={_us(s['p50_us'])} "
           f"p99={_us(s['p99_us'])} batches={s['batches']}")
     route_rows = eng.route_summary()
     for row in route_rows:
@@ -640,12 +640,11 @@ def main(argv=None) -> dict:
           f"{args.events / dt:,.0f} ev/s ({_device_label()}, "
           f"{args.replicas} replica(s), {args.policy}, "
           f"{args.loop} loop)")
-    print(f"[serve] latency p50={_us(s['p50_us'])} "
+    print(f"[serve] latency to release p50={_us(s['p50_us'])} "
           f"p99={_us(s['p99_us'])} batches={s['batches']}")
-    bud = s["budget"]
-    print(f"[serve] budget queue_wait={_us(bud['queue_wait_us_mean'])} "
-          f"dispatch={_us(bud['dispatch_us_mean'])} "
-          f"compute={_us(bud['compute_us_mean'])}")
+    print("[serve] budget " + " ".join(
+        f"{k.removesuffix('_us_mean')}={_us(v)}"
+        for k, v in s["budget"].items() if v is not None))
     for rs in s["per_replica"]:
         print(f"[serve]   replica {rs['replica_id']}: "
               f"{rs['completed']} events, {rs['batches']} batches, "

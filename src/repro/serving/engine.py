@@ -37,7 +37,7 @@ from repro.serving.health import BreakerConfig, ReplicaHealth
 from repro.serving.monitor import MonitorSnapshot, TriggerMonitor
 from repro.serving.replica import (EventTiming, InOrderReleaser,
                                    ReplicaEngine, ServingStats,
-                                   ShedError)
+                                   ShedError, budget)
 from repro.serving.router import (POLICIES, Router, event_occupancy,
                                   pick_bucket_sorted)
 from repro.serving.streaming import LOOPS, StreamingReplicaEngine
@@ -103,13 +103,6 @@ class AggregateStats:
 
     def summary(self):
         lat = np.asarray(self.latencies_s)   # one merged copy per call
-
-        def merged_mean_us(field):
-            xs = []
-            for r in self._replicas:
-                xs.extend(r.stats.samples(field))
-            return float(np.mean(xs)) * 1e6 if xs else None
-
         agg = {
             "replicas": len(self._replicas),
             "completed": self.completed,
@@ -126,11 +119,7 @@ class AggregateStats:
             if lat.size else None,
             "mean_us": float(lat.mean()) * 1e6 if lat.size else None,
             "throughput_ev_s": self.throughput_ev_s(),
-            "budget": {
-                "queue_wait_us_mean": merged_mean_us("queue_wait_s"),
-                "dispatch_us_mean": merged_mean_us("dispatch_s"),
-                "compute_us_mean": merged_mean_us("compute_s"),
-            },
+            "budget": budget([r.stats for r in self._replicas]),
         }
         agg["per_replica"] = [r.stats.summary() for r in self._replicas]
         return agg
@@ -540,7 +529,7 @@ class ShardedTriggerService:
         st = self.replicas[timing.replica_id].stats
         kind, value = outcome
         if kind == "ok":
-            st.record_release(timing)
+            st.record_release(timing, time.perf_counter())
             if not fut.cancelled():   # client gave up; stats still count
                 fut.set_result(value)
         else:

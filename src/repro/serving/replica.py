@@ -18,13 +18,11 @@ request/response-shaped micro-batcher); ``streaming.py`` subclasses
 (preallocated input/output rings, rolling batching, no deadline tick).
 The service selects between them with ``loop=``.
 
-Latency budget accounting (paper §III): each event's end-to-end latency
-is split into
-
-  queue_wait — submit() until the collector pops the event;
-  dispatch   — batch assembly: fill-window residency after the pop,
-               stacking/zero-padding, and device placement;
-  compute    — the inference call itself (including any hedged retry).
+Latency budget accounting (paper §III): each event's queue wait
+(submit() until the collector pops it) is sampled per event, its
+latency is taken at release, and every launch's stages (pack, the
+pipeline call, fetch, release; ``repro.spans``) add to the lane's
+``spans.StageCounters``.
 
 Fault tolerance (docs/serving.md): ``faults=`` wraps the replica's
 ``infer_fn`` with a deterministic injector (``serving.faults``),
@@ -48,6 +46,8 @@ from concurrent.futures import wait as futures_wait
 
 import numpy as np
 
+from repro import spans
+
 # per-replica sliding window for latency/budget samples; counters stay
 # exact, percentiles reflect the most recent window.
 STAT_WINDOW = 65536
@@ -62,28 +62,15 @@ class ShedError(RuntimeError):
 
 @dataclasses.dataclass
 class EventTiming:
-    """perf_counter timestamps for one event's trip through a replica."""
+    """perf_counter timestamps for one event's trip through a replica:
+    submitted, and popped from the lane's queue."""
     replica_id: int
     t_submit: float
     t_collect: float
-    t_dispatch: float
-    t_done: float
-
-    @property
-    def latency_s(self):
-        return self.t_done - self.t_submit
 
     @property
     def queue_wait_s(self):
         return self.t_collect - self.t_submit
-
-    @property
-    def dispatch_s(self):
-        return self.t_dispatch - self.t_collect
-
-    @property
-    def compute_s(self):
-        return self.t_done - self.t_dispatch
 
 
 def _pct(xs, p):
@@ -95,6 +82,23 @@ def _stat_window():
     return deque(maxlen=STAT_WINDOW)
 
 
+def budget(stats) -> dict:
+    """The latency budget of the lanes whose ``ServingStats`` are
+    ``stats``: ``queue_wait_us_mean`` per event, and
+    ``<stage>_us_mean`` per launch for every lane stage of
+    ``repro.spans`` (None where nothing was recorded)."""
+    waits = [x for st in stats for x in st.samples("queue_wait_s")]
+    out = {"queue_wait_us_mean":
+           float(np.mean(waits)) * 1e6 if waits else None}
+    snaps = [st.stages.snapshot() for st in stats]
+    for name in spans.LANE_STAGES:
+        n = sum(sn[name]["count"] for sn in snaps if name in sn)
+        t = sum(sn[name]["total_s"] for sn in snaps if name in sn)
+        key = name.removeprefix("trigger.") + "_us_mean"
+        out[key] = t / n * 1e6 if n else None
+    return out
+
+
 @dataclasses.dataclass
 class ServingStats:
     """Per-replica counters + bounded sliding-window latency samples
@@ -104,8 +108,9 @@ class ServingStats:
     ``summary()``).
 
     ``latencies_s``/``completed`` are updated by the release stage (so
-    they observe strict release order); the batch counters are updated
-    by the replica's dispatch loop.  Readers (``summary``, monitoring
+    they observe strict release order; a latency runs from submit to
+    release); the batch and stage counters are updated by the
+    replica's loop.  Readers (``summary``, monitoring
     threads) must go through ``samples()``, which snapshots a deque
     under the stats lock — iterating a deque while the releaser
     appends to it raises RuntimeError.
@@ -126,8 +131,10 @@ class ServingStats:
     failed_over: int = 0
     latencies_s: deque = dataclasses.field(default_factory=_stat_window)
     queue_wait_s: deque = dataclasses.field(default_factory=_stat_window)
-    dispatch_s: deque = dataclasses.field(default_factory=_stat_window)
-    compute_s: deque = dataclasses.field(default_factory=_stat_window)
+    # per-launch stage durations (repro.spans), exact for the lifetime
+    # of the replica
+    stages: spans.StageCounters = dataclasses.field(
+        default_factory=spans.StageCounters)
     # throughput clock: stamped by the first enqueue, not construction,
     # so a replica built long before traffic reports an honest rate.
     started_at: float | None = None
@@ -146,13 +153,11 @@ class ServingStats:
     def percentile(self, p):
         return _pct(self.samples("latencies_s"), p)
 
-    def record_release(self, timing: EventTiming):
+    def record_release(self, timing: EventTiming, t_release: float):
         with self.lock:
             self.completed += 1
-            self.latencies_s.append(timing.latency_s)
+            self.latencies_s.append(t_release - timing.t_submit)
             self.queue_wait_s.append(timing.queue_wait_s)
-            self.dispatch_s.append(timing.dispatch_s)
-            self.compute_s.append(timing.compute_s)
 
     def record_devices(self, leaves):
         placed = {d for leaf in leaves if hasattr(leaf, "devices")
@@ -167,15 +172,9 @@ class ServingStats:
         return self.completed / dt if dt > 0 else 0.0
 
     def budget(self):
-        """Mean per-event latency-budget split, in µs."""
-        def mean_us(xs):
-            return float(np.fromiter(xs, float).mean()) * 1e6 \
-                if xs else None
-        return {
-            "queue_wait_us_mean": mean_us(self.samples("queue_wait_s")),
-            "dispatch_us_mean": mean_us(self.samples("dispatch_s")),
-            "compute_us_mean": mean_us(self.samples("compute_s")),
-        }
+        """Mean queue wait per event and mean time per launch of each
+        lane stage, in µs (None where nothing was recorded)."""
+        return budget([self])
 
     def summary(self):
         lat = self.samples("latencies_s")
@@ -479,8 +478,7 @@ class ReplicaEngine:
             if self._truth_map is not None:
                 self._truth_map.pop(seq, None)
             t_collect = it[2] if len(it) == 5 else now
-            timing = EventTiming(self.replica_id, t_submit, t_collect,
-                                 now, now)
+            timing = EventTiming(self.replica_id, t_submit, t_collect)
             self._releaser.complete(seq, ("err", exc), timing, fut)
 
     def _dispatch(self, items):
@@ -492,60 +490,65 @@ class ReplicaEngine:
     def _run_batch(self, items):
         n = len(items)
         pad = self.microbatch - n
-        feeds = {}
-        for key in items[0][3]:
-            stacked = np.stack([it[3][key] for it in items])
-            if pad:
-                z = np.zeros((pad, *stacked.shape[1:]), stacked.dtype)
-                stacked = np.concatenate([stacked, z])
-            feeds[key] = stacked
-        with self._count_lock:
-            # batches counts *launched* batches — a failing inference
-            # below still launched one.
-            self.stats.batches += 1
-            self.stats.padded_events += pad
-        if self.device is not None:
-            import jax
-            feeds = jax.device_put(feeds, self.device)
-        t_dispatch = time.perf_counter()
+        counters = self.stats.stages
+        with spans.stage(spans.PACK, counters, events=n):
+            feeds = {}
+            for key in items[0][3]:
+                stacked = np.stack([it[3][key] for it in items])
+                if pad:
+                    z = np.zeros((pad, *stacked.shape[1:]), stacked.dtype)
+                    stacked = np.concatenate([stacked, z])
+                feeds[key] = stacked
+            with self._count_lock:
+                # batches counts *launched* batches — a failing
+                # inference below still launched one.
+                self.stats.batches += 1
+                self.stats.padded_events += pad
+            if self.device is not None:
+                import jax
+                feeds = jax.device_put(feeds, self.device)
         try:
-            out = self._call(feeds)
+            with spans.stage(spans.PIPELINE, counters, events=n):
+                out = self._call(feeds)
         except Exception as exc:  # noqa: BLE001 — fault isolation: fail
-            self._fail_batch(items, exc, t_dispatch)   # the batch, not
-            return                                     # the replica
+            self._fail_batch(items, exc)   # the batch, not the replica
+            return
         if self._health is not None:
             self._health.record_success()
         import jax
         leaves, tdef = jax.tree_util.tree_flatten(out)
         self.stats.record_devices(leaves)
-        # materialize BEFORE stamping t_done: under jax async dispatch
-        # the call above returns unfinished arrays, and the compute
-        # budget must include the actual device time.
-        np_leaves = [np.asarray(l) for l in leaves]
-        t_done = time.perf_counter()
-        if self._monitor is not None:
-            # one deque append; the truth pops stay here (not in the
-            # deferred fold) so the side-channel map stays bounded by
-            # in-flight events even if no reader ever drains.  Only
-            # the CPS subtree is staged — np.asarray after the
-            # materialization above is a cheap view, and staging the
-            # full result/items would pin inputs and futures.
-            truths = [self._truth_map.pop(it[0], None) for it in items] \
-                if self._truth_map else None
-            cps = out.get("cps", out) if isinstance(out, dict) else None
-            rec = {k: np.asarray(v) for k, v in cps.items()
-                   if not isinstance(v, dict)} \
-                if isinstance(cps, dict) else None
-            self._monitor.record_raw(
-                rec, [(it[0], it[1]) for it in items], t_done, truths)
-        for i, (seq, t_submit, t_collect, _, fut) in enumerate(items):
-            res = jax.tree_util.tree_unflatten(
-                tdef, [l[i] for l in np_leaves])
-            timing = EventTiming(self.replica_id, t_submit, t_collect,
-                                 t_dispatch, t_done)
-            self._releaser.complete(seq, ("ok", res), timing, fut)
+        # under jax async dispatch the call above returns unfinished
+        # arrays: the fetch waits for the device.
+        with spans.stage(spans.FETCH, counters):
+            np_leaves = [np.asarray(l) for l in leaves]
+        with spans.stage(spans.RELEASE, counters, events=n) as st:
+            if self._monitor is not None:
+                # one deque append; the truth pops stay here (not in
+                # the deferred fold) so the side-channel map stays
+                # bounded by in-flight events even if no reader ever
+                # drains.  Only the CPS subtree is staged —
+                # np.asarray after the materialization above is a
+                # cheap view, and staging the full result/items would
+                # pin inputs and futures.
+                truths = [self._truth_map.pop(it[0], None)
+                          for it in items] if self._truth_map else None
+                cps = out.get("cps", out) if isinstance(out, dict) \
+                    else None
+                rec = {k: np.asarray(v) for k, v in cps.items()
+                       if not isinstance(v, dict)} \
+                    if isinstance(cps, dict) else None
+                self._monitor.record_raw(
+                    rec, [(it[0], it[1]) for it in items],
+                    time.perf_counter(), truths)
+            for i, (seq, t_submit, t_collect, _, fut) in enumerate(items):
+                res = jax.tree_util.tree_unflatten(
+                    tdef, [l[i] for l in np_leaves])
+                timing = EventTiming(self.replica_id, t_submit, t_collect)
+                self._releaser.complete(seq, ("ok", res), timing, fut)
+            st.set(held=self._releaser.pending)
 
-    def _fail_batch(self, items, exc, t_dispatch):
+    def _fail_batch(self, items, exc):
         """Batch-failure path: feed the breaker, offer the events to
         the service's failover hook (bounded re-dispatch to a healthy
         sibling in the same group), then fail whatever could not be
@@ -564,12 +567,10 @@ class ReplicaEngine:
                 self.stats.failed_over += moved
         if not remaining:
             return
-        t_done = time.perf_counter()
         for seq, t_submit, t_collect, _, fut in remaining:
             if self._truth_map is not None:
                 self._truth_map.pop(seq, None)
-            timing = EventTiming(self.replica_id, t_submit, t_collect,
-                                 t_dispatch, t_done)
+            timing = EventTiming(self.replica_id, t_submit, t_collect)
             self._releaser.complete(seq, ("err", exc), timing, fut)
 
     def _call(self, feeds):

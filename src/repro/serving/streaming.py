@@ -53,6 +53,7 @@ from concurrent.futures import TimeoutError as FuturesTimeout
 
 import numpy as np
 
+from repro import spans
 from repro.serving.replica import EventTiming, ReplicaEngine
 
 # replica loop flavors a ShardedTriggerService can run
@@ -141,31 +142,35 @@ class StreamingReplicaEngine(ReplicaEngine):
                 return
             staged = [(seq, t_submit, time.perf_counter(), event, fut)]
             acquired = False
-            while not (acquired := self._inflight_sem.acquire(
-                    timeout=_POLL_S)):
-                if self._stop.is_set():
-                    break
+            with spans.stage(spans.SLOT_WAIT, self.stats.stages):
+                while not (acquired := self._inflight_sem.acquire(
+                        timeout=_POLL_S)):
+                    if self._stop.is_set():
+                        break
             if not acquired:
                 self._fail_items(staged)   # closing: don't strand futures
                 return
-            now = time.perf_counter()
-            while len(staged) < self.microbatch:
+            with spans.stage(spans.PACK, self.stats.stages) as st:
+                now = time.perf_counter()
+                while len(staged) < self.microbatch:
+                    try:
+                        s, t, ev, f = self._q.get_nowait()
+                    except queue.Empty:
+                        break
+                    dl = getattr(f, "deadline", None)
+                    if dl is not None and now > dl:
+                        self._shed_items([(s, t, ev, f)],
+                                         "deadline expired in queue")
+                        continue
+                    staged.append((s, t, now, ev, f))
+                st.set(events=len(staged), queued=self._q.qsize())
                 try:
-                    s, t, ev, f = self._q.get_nowait()
-                except queue.Empty:
-                    break
-                dl = getattr(f, "deadline", None)
-                if dl is not None and now > dl:
-                    self._shed_items([(s, t, ev, f)],
-                                     "deadline expired in queue")
-                    continue
-                staged.append((s, t, now, ev, f))
-            try:
-                self._launch(staged)
-            except Exception:  # noqa: BLE001 — a malformed event (e.g.
-                # missing feed key) fails its own launch, never the lane
-                self._inflight_sem.release()
-                self._fail_items(staged)
+                    self._launch(staged)
+                except Exception:  # noqa: BLE001 — a malformed event
+                    # (e.g. missing feed key) fails its own launch,
+                    # never the lane
+                    self._inflight_sem.release()
+                    self._fail_items(staged)
 
     def _pack(self, items, slot_i: int) -> dict:
         """Copy the staged events into input-ring slot ``slot_i`` and
@@ -214,11 +219,12 @@ class StreamingReplicaEngine(ReplicaEngine):
         if self.device is not None:
             import jax
             feeds = jax.device_put(feeds, self.device)
-        rec = {"items": items, "t_dispatch": time.perf_counter()}
+        rec = {"items": items}
+        n = len(items)
 
-        def _call(feeds=feeds, rec=rec):
-            rec["t_dispatch"] = time.perf_counter()
-            return self._infer(feeds)
+        def _call(feeds=feeds):
+            with spans.stage(spans.PIPELINE, self.stats.stages, events=n):
+                return self._infer(feeds)
 
         # async dispatch: the launcher hands the launch off and goes
         # straight back to assembling the next one.
@@ -247,29 +253,32 @@ class StreamingReplicaEngine(ReplicaEngine):
     def _poll_result(self, fut):
         """Poll the launch future (never an unbounded block, so a
         wedged backend can't make shutdown unresponsive)."""
-        while True:
-            try:
-                return fut.result(timeout=_POLL_S)
-            except FuturesTimeout:
-                continue
+        with spans.stage(spans.RESULT_WAIT, self.stats.stages):
+            while True:
+                try:
+                    return fut.result(timeout=_POLL_S)
+                except FuturesTimeout:
+                    continue
 
     def _to_host_ring(self, leaves) -> list:
         """D2H stage: poll the device buffers, then copy every leaf
         into the preallocated host output-ring slot."""
         if leaves and hasattr(leaves[0], "is_ready"):
-            while not all(l.is_ready() for l in leaves):
-                time.sleep(5e-5)
-        views = [np.asarray(l) for l in leaves]
-        out_i = self._out_idx
-        self._out_idx = (out_i + 1) % len(self._out_ring)
-        slot = self._out_ring[out_i]
-        if (slot is None or len(slot) != len(views)
-                or any(s.shape != v.shape or s.dtype != v.dtype
-                       for s, v in zip(slot, views))):
-            slot = self._out_ring[out_i] = [np.empty(v.shape, v.dtype)
-                                            for v in views]
-        for s, v in zip(slot, views):
-            np.copyto(s, v)
+            with spans.stage(spans.DEVICE_WAIT, self.stats.stages):
+                while not all(l.is_ready() for l in leaves):
+                    time.sleep(5e-5)
+        with spans.stage(spans.FETCH, self.stats.stages):
+            views = [np.asarray(l) for l in leaves]
+            out_i = self._out_idx
+            self._out_idx = (out_i + 1) % len(self._out_ring)
+            slot = self._out_ring[out_i]
+            if (slot is None or len(slot) != len(views)
+                    or any(s.shape != v.shape or s.dtype != v.dtype
+                           for s, v in zip(slot, views))):
+                slot = self._out_ring[out_i] = [
+                    np.empty(v.shape, v.dtype) for v in views]
+            for s, v in zip(slot, views):
+                np.copyto(s, v)
         return slot
 
     def _harvest(self, rec):
@@ -279,7 +288,7 @@ class StreamingReplicaEngine(ReplicaEngine):
         except Exception as exc:  # noqa: BLE001 — fault isolation: fail
             # the launch, not the lane; breaker + failover as in the
             # deadline loop's batch-failure path
-            self._fail_batch(items, exc, rec["t_dispatch"])
+            self._fail_batch(items, exc)
             return
         if self._health is not None:
             self._health.record_success()
@@ -287,27 +296,31 @@ class StreamingReplicaEngine(ReplicaEngine):
         leaves, tdef = jax.tree_util.tree_flatten(out)
         self.stats.record_devices(leaves)
         host = self._to_host_ring(leaves)
-        t_done = time.perf_counter()
-        if self._monitor is not None:
-            truths = [self._truth_map.pop(it[0], None) for it in items] \
-                if self._truth_map else None
-            outv = jax.tree_util.tree_unflatten(tdef, host)
-            cps = outv.get("cps", outv) if isinstance(outv, dict) else None
-            # copies, not views: the output-ring slot is reused while
-            # the monitor's staged record is folded lazily much later.
-            md = {k: np.array(v) for k, v in cps.items()
-                  if not isinstance(v, dict)} \
-                if isinstance(cps, dict) else None
-            self._monitor.record_raw(
-                md, [(it[0], it[1]) for it in items], t_done, truths)
-        for i, (seq, t_submit, t_collect, _, fut) in enumerate(items):
-            # per-event copies for the same reason: futures outlive the
-            # ring slot's next reuse.
-            res = jax.tree_util.tree_unflatten(
-                tdef, [np.array(l[i]) for l in host])
-            timing = EventTiming(self.replica_id, t_submit, t_collect,
-                                 rec["t_dispatch"], t_done)
-            self._releaser.complete(seq, ("ok", res), timing, fut)
+        with spans.stage(spans.RELEASE, self.stats.stages,
+                         events=len(items)) as st:
+            if self._monitor is not None:
+                truths = [self._truth_map.pop(it[0], None)
+                          for it in items] if self._truth_map else None
+                outv = jax.tree_util.tree_unflatten(tdef, host)
+                cps = outv.get("cps", outv) if isinstance(outv, dict) \
+                    else None
+                # copies, not views: the output-ring slot is reused
+                # while the monitor's staged record is folded lazily
+                # much later.
+                md = {k: np.array(v) for k, v in cps.items()
+                      if not isinstance(v, dict)} \
+                    if isinstance(cps, dict) else None
+                self._monitor.record_raw(
+                    md, [(it[0], it[1]) for it in items],
+                    time.perf_counter(), truths)
+            for i, (seq, t_submit, t_collect, _, fut) in enumerate(items):
+                # per-event copies for the same reason: futures outlive
+                # the ring slot's next reuse.
+                res = jax.tree_util.tree_unflatten(
+                    tdef, [np.array(l[i]) for l in host])
+                timing = EventTiming(self.replica_id, t_submit, t_collect)
+                self._releaser.complete(seq, ("ok", res), timing, fut)
+            st.set(held=self._releaser.pending)
 
     # ----------------------------------------------------------- control ----
     def close(self):

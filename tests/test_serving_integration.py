@@ -178,11 +178,16 @@ def test_aggregate_stats_report_per_replica_budget():
     assert s["replicas"] == 2 and len(s["per_replica"]) == 2
     assert s["completed"] == 16
     bud = s["budget"]
-    for k in ("queue_wait_us_mean", "dispatch_us_mean", "compute_us_mean"):
+    # the deadline loop's stages, per launch; the echo pipeline sleeps
+    # 5 ms in its call
+    for k in ("queue_wait_us_mean", "pack_us_mean", "pipeline_us_mean",
+              "fetch_us_mean", "release_us_mean"):
         assert bud[k] is not None and bud[k] >= 0.0
+    assert bud["pipeline_us_mean"] >= 5000.0
+    assert bud["slot_wait_us_mean"] is None   # a streaming-loop stage
     # per-replica budgets carry the same breakdown
     for rs in s["per_replica"]:
-        assert rs["budget"]["compute_us_mean"] > 0.0
+        assert rs["budget"]["pipeline_us_mean"] >= 5000.0
     svc.close()
 
 

@@ -15,6 +15,7 @@ imports this file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -288,3 +289,40 @@ def test_deployed_gatedgcn_pipeline_compiles(spec):
         "edge_index": spec((mb, 2, e), jnp.int32),
         "edges": spec((mb, e, gcfg.d_edge_in)),
         "node_mask": spec((mb, n)), "edge_mask": spec((mb, e))}))
+
+
+def _hlo_without_metadata(lowered) -> str:
+    """The compiled program's computations, metadata left out."""
+    text = lowered.compile().as_text()
+    return re.sub(r", metadata=\{[^}]*\}", "", text[text.index("\n%"):])
+
+
+@pytest.mark.parametrize("ragged", [False, True],
+                         ids=["per_event", "ragged"])
+def test_op_scopes_change_only_metadata(spec, monkeypatch, ragged):
+    """Every graph op lowers under ``jax.named_scope(<op name>)``: its
+    name reaches the device operations' ``op_name`` metadata, and the
+    compiled program is otherwise the same, instruction and kernel
+    names included (the benchmark finds kernels by those names)."""
+    import contextlib
+    g, cfg = _ccn_graph()
+    pipe = deploy(g, _tpu_req(precision_policy="fp"),
+                  kernel_backend="pallas", batch=B if ragged else 1,
+                  ragged=ragged)
+    pipe = pipe.pipe if ragged else pipe
+    mb = pipe.microbatch
+    feeds = {"hits": spec((mb, N, cfg.d_in)), "mask": spec((mb, N))}
+    if ragged:
+        feeds.update(segids=spec((mb, N), jnp.int32),
+                     slots=spec((mb, N), jnp.int32))
+    jax.clear_caches()      # both launches traced afresh
+    scoped = pipe.lower(feeds)
+    text = scoped.compile().as_text()
+    assert 'op_name="jit(whole)/cps/' in text
+    assert 'op_name="jit(whole)/gn0_agg.block/' in text
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    plain = pipe.lower(feeds)
+    assert 'op_name="jit(whole)/cps/' not in plain.compile().as_text()
+    assert _hlo_without_metadata(scoped) == _hlo_without_metadata(plain)
