@@ -115,54 +115,67 @@ def apply(params, feats, mask, cfg: CCNConfig):
 
 
 # ------------------------------------------------------------------- CPS ----
+def _best_candidate(cand, beta, x, y, energy):
+    """Per event, the candidate with the highest β, the lowest index
+    among equal β: one reduction over the hits that carries the winner's
+    coordinates and energy along, so nothing is gathered. Returns the
+    winner's ``(beta, x, y, energy)``; where no candidate is left its β
+    is -inf."""
+    key = jnp.where(cand, beta, -jnp.inf)
+    idx = jax.lax.broadcasted_iota(jnp.int32, beta.shape, 1)
+
+    def better(a, b):
+        take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jnp.where(take_a, u, v) for u, v in zip(a, b))
+
+    init = (jnp.float32(-jnp.inf), jnp.int32(beta.shape[1]),
+            jnp.float32(0), jnp.float32(0), jnp.float32(0))
+    b, _, bx, by, be = jax.lax.reduce((key, idx, x, y, energy), init,
+                                      better, (1,))
+    return b, bx, by, be
+
+
 def cps(outputs, mask, cfg: CCNConfig):
-    """Condensation Point Selection (vmapped over the batch).
+    """Condensation Point Selection over a batch of events.
 
     Greedy over hits in decreasing β: select hits with β > t_beta that are
-    at least t_dist away (in learned cluster-coordinate space) from every
-    already-selected point; at most k_max points. Fixed shapes throughout
-    (jit/hardware friendly — the paper runs this on FPGA fabric; here it
-    is the canonical 'irregular' op pinned to the XLA partition).
+    more than t_dist away (in learned cluster-coordinate space) from every
+    already-selected point; at most k_max points.
+
+    Run as ``k_max`` rounds of non-maximum suppression, unrolled: round j
+    takes each event's highest-β surviving candidate (the lowest index
+    among equal β, the order a stable sort by decreasing β visits them
+    in), writes it to slot j, and drops every candidate within t_dist of
+    it, itself included. The hit the greedy scan accepts j-th is the
+    first in β order that is above t_beta and far from the j picks before
+    it, which is exactly that round's winner, and a squared distance is
+    the same two squares summed, so the result is the scan's bit for
+    bit. Once no candidate is left every later round finds none either.
+    Fixed shapes throughout, k_max steps over whole events instead of
+    one step per hit, no sort and no per-hit state update (the paper
+    runs this on FPGA fabric; here it is the canonical 'irregular' op
+    pinned to the XLA partition).
     """
-    def one_event(beta_logit, coords, energy, mask_e):
-        n = beta_logit.shape[0]
-        beta = jax.nn.sigmoid(beta_logit) * mask_e
-        order = jnp.argsort(-beta)
-        big = jnp.float32(1e30)
-
-        def body(t, carry):
-            sel_xy, sel_e, sel_b, count = carry
-            idx = order[t]
-            b = beta[idx]
-            c = coords[idx]
-            d2 = jnp.sum((sel_xy - c[None, :]) ** 2, axis=1)
-            d2 = jnp.where(jnp.arange(cfg.k_max) < count, d2, big)
-            ok = ((b > cfg.t_beta)
-                  & (jnp.min(d2) > cfg.t_dist ** 2)
-                  & (count < cfg.k_max))
-            slot = count
-            sel_xy = jnp.where(ok, sel_xy.at[slot].set(c), sel_xy)
-            sel_e = jnp.where(ok, sel_e.at[slot].set(energy[idx]), sel_e)
-            sel_b = jnp.where(ok, sel_b.at[slot].set(b), sel_b)
-            count = count + jnp.where(ok, 1, 0)
-            return sel_xy, sel_e, sel_b, count
-
-        init = (jnp.zeros((cfg.k_max, 2), jnp.float32),
-                jnp.zeros((cfg.k_max,), jnp.float32),
-                jnp.zeros((cfg.k_max,), jnp.float32),
-                jnp.int32(0))
-        sel_xy, sel_e, sel_b, count = jax.lax.fori_loop(0, n, body, init)
-        valid = jnp.arange(cfg.k_max) < count
-        trigger = jnp.any(valid & (sel_e > cfg.e_trigger))
-        return {"cluster_xy": sel_xy, "cluster_e": sel_e,
-                "cluster_beta": sel_b, "cluster_valid": valid,
-                "n_clusters": count, "trigger": trigger}
-
-    return jax.vmap(one_event)(
-        outputs["beta_logit"].astype(jnp.float32),
-        outputs["coords"].astype(jnp.float32),
-        outputs["energy"].astype(jnp.float32),
-        mask.astype(jnp.float32))
+    beta = (jax.nn.sigmoid(outputs["beta_logit"].astype(jnp.float32))
+            * mask.astype(jnp.float32))
+    coords = outputs["coords"].astype(jnp.float32)
+    x, y = coords[..., 0], coords[..., 1]
+    energy = outputs["energy"].astype(jnp.float32)
+    cand = beta > cfg.t_beta
+    picks = []
+    for _ in range(cfg.k_max):
+        b, px, py, e = _best_candidate(cand, beta, x, y, energy)
+        picks.append((b, px, py, e))
+        d2 = (x - px[:, None]) ** 2 + (y - py[:, None]) ** 2
+        cand = cand & (d2 > cfg.t_dist ** 2)
+    sel_b, sel_x, sel_y, sel_e = (jnp.stack(v, axis=1) for v in zip(*picks))
+    valid = sel_b > -jnp.inf
+    sel_b, sel_e = (jnp.where(valid, v, 0.0) for v in (sel_b, sel_e))
+    sel_xy = jnp.where(valid[..., None], jnp.stack([sel_x, sel_y], -1), 0.0)
+    return {"cluster_xy": sel_xy, "cluster_e": sel_e,
+            "cluster_beta": sel_b, "cluster_valid": valid,
+            "n_clusters": jnp.sum(valid, axis=1, dtype=jnp.int32),
+            "trigger": jnp.any(valid & (sel_e > cfg.e_trigger), axis=1)}
 
 
 # -------------------------------------------------------------- IR export ----

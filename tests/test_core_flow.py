@@ -1,4 +1,6 @@
 """Tests for the deployment flow: IR, passes, pipeline, quantization, CPS."""
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -251,6 +253,116 @@ def test_cps_property_count_matches_validmask(seed):
     np.testing.assert_array_equal(
         np.asarray(res["cluster_valid"]).sum(-1),
         np.asarray(res["n_clusters"]))
+
+
+def _greedy_cps_loop(outputs, mask, cfg):
+    """The greedy scan ``ccn.cps`` replaces, kept as its oracle: every hit
+    in decreasing β (stable sort), one loop step per hit."""
+    def one_event(beta_logit, coords, energy, mask_e):
+        beta = jax.nn.sigmoid(beta_logit) * mask_e
+        order = jnp.argsort(-beta)
+
+        def body(t, carry):
+            sel_xy, sel_e, sel_b, count = carry
+            idx = order[t]
+            b, c = beta[idx], coords[idx]
+            d2 = jnp.sum((sel_xy - c[None, :]) ** 2, axis=1)
+            d2 = jnp.where(jnp.arange(cfg.k_max) < count, d2, 1e30)
+            ok = ((b > cfg.t_beta) & (jnp.min(d2) > cfg.t_dist ** 2)
+                  & (count < cfg.k_max))
+            sel_xy = jnp.where(ok, sel_xy.at[count].set(c), sel_xy)
+            sel_e = jnp.where(ok, sel_e.at[count].set(energy[idx]), sel_e)
+            sel_b = jnp.where(ok, sel_b.at[count].set(b), sel_b)
+            return sel_xy, sel_e, sel_b, count + jnp.where(ok, 1, 0)
+
+        init = (jnp.zeros((cfg.k_max, 2), jnp.float32),
+                jnp.zeros((cfg.k_max,), jnp.float32),
+                jnp.zeros((cfg.k_max,), jnp.float32), jnp.int32(0))
+        sel_xy, sel_e, sel_b, count = jax.lax.fori_loop(
+            0, beta.shape[0], body, init)
+        valid = jnp.arange(cfg.k_max) < count
+        return {"cluster_xy": sel_xy, "cluster_e": sel_e,
+                "cluster_beta": sel_b, "cluster_valid": valid,
+                "n_clusters": count,
+                "trigger": jnp.any(valid & (sel_e > cfg.e_trigger))}
+
+    return jax.vmap(one_event)(outputs["beta_logit"], outputs["coords"],
+                               outputs["energy"], mask)
+
+
+_cps_jit = jax.jit(ccn.cps, static_argnums=2)
+_greedy_jit = jax.jit(_greedy_cps_loop, static_argnums=2)
+
+
+def _cps_inputs(kind, batch, n, seed):
+    """CPS inputs of one kind; each event keeps 10-100% of its hits."""
+    rng = np.random.default_rng(seed)
+    logit = rng.normal(0.0, 2.0, (batch, n))
+    xy = rng.uniform(-2.0, 2.0, (batch, n, 2))
+    mask = rng.uniform(size=(batch, n)) < rng.uniform(0.1, 1.0, (batch, 1))
+    if kind == "ties":            # a handful of β values, many equal
+        logit = np.round(logit)
+    elif kind == "grid":          # 0.01 grid: distances near t_dist
+        xy = np.round(rng.uniform(-1.0, 1.0, (batch, n, 2)), 2)
+        logit = np.round(logit)
+    elif kind == "lattice":       # 0.25 lattice: distances exactly t_dist
+        xy = rng.integers(-4, 5, (batch, n, 2)) * 0.25
+        logit = np.round(logit)
+    elif kind == "all_masked":
+        mask[:] = False
+    elif kind == "none_above":    # every β under t_beta
+        logit = rng.uniform(-6.0, -1.0, (batch, n))
+    elif kind == "separable":     # every hit a candidate, all far apart
+        logit = rng.uniform(0.0, 3.0, (batch, n))
+        mask[:] = True
+        side = int(np.ceil(np.sqrt(n)))
+        cells = np.stack(np.divmod(np.arange(side * side), side), -1)
+        xy = np.stack([cells[rng.permutation(side * side)[:n]]
+                       for _ in range(batch)]).astype(np.float64)
+    outputs = {"beta_logit": jnp.asarray(logit, jnp.float32),
+               "coords": jnp.asarray(xy, jnp.float32),
+               "energy": jnp.asarray(rng.uniform(0, 2, (batch, n)),
+                                     jnp.float32)}
+    return outputs, jnp.asarray(mask, jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "grid", "lattice",
+                                  "all_masked", "none_above",
+                                  "separable"])
+@pytest.mark.parametrize("batch", [1, 16])
+@pytest.mark.parametrize("n_hits", [128, 32])
+def test_cps_rounds_match_greedy_loop_bitwise(n_hits, batch, kind):
+    """``ccn.cps`` (k_max suppression rounds) equals the per-hit greedy
+    scan bit for bit, on every output."""
+    cfg = ccn.CCNConfig(n_hits=n_hits)
+    for seed in range(3):
+        outputs, mask = _cps_inputs(kind, batch, n_hits, seed)
+        got = _cps_jit(outputs, mask, cfg)
+        want = _greedy_jit(outputs, mask, cfg)
+        assert got.keys() == want.keys()
+        for key in want:
+            g, w = np.asarray(got[key]), np.asarray(want[key])
+            assert (g.dtype, g.shape) == (w.dtype, w.shape), key
+            assert g.tobytes() == w.tobytes(), (key, seed, g, w)
+        count = np.asarray(got["n_clusters"])
+        if kind in ("all_masked", "none_above"):
+            assert not count.any()
+        if kind == "separable":
+            assert np.all(count == cfg.k_max)
+
+
+def test_cps_lowers_without_loop_or_sort():
+    """The CPS is k_max unrolled rounds: no while loop and no sort at the
+    served shape (16 events of 128 hits)."""
+    cfg = ccn.CCNConfig()
+    f32 = jnp.float32
+    outputs = {"beta_logit": jax.ShapeDtypeStruct((16, 128), f32),
+               "coords": jax.ShapeDtypeStruct((16, 128, 2), f32),
+               "energy": jax.ShapeDtypeStruct((16, 128), f32)}
+    lowered = jax.jit(ccn.cps, static_argnums=2).lower(
+        outputs, jax.ShapeDtypeStruct((16, 128), f32), cfg)
+    assert not re.search(r"stablehlo\.(while|sort)\b", lowered.as_text())
+    assert not re.search(r"\b(while|sort)\(", lowered.compile().as_text())
 
 
 # ------------------------------------------------------------- training ----

@@ -291,6 +291,22 @@ def test_deployed_gatedgcn_pipeline_compiles(spec):
         "node_mask": spec((mb, n)), "edge_mask": spec((mb, e))}))
 
 
+def test_deployed_cps_scope_has_no_loop_or_sort(spec):
+    """The per-event program's CPS compiles for the chip as k_max
+    unrolled rounds: no instruction under the ``cps`` scope is a while
+    loop or a sort."""
+    g, cfg = _ccn_graph()
+    pipe = deploy(g, _tpu_req(precision_policy="fp"),
+                  kernel_backend="pallas", batch=1)
+    text = pipe.lower({"hits": spec((pipe.microbatch, N, cfg.d_in)),
+                       "mask": spec((pipe.microbatch, N))}
+                      ).compile().as_text()
+    scoped = [ln for ln in text.splitlines()
+              if 'op_name="jit(whole)/cps/' in ln]
+    assert scoped
+    assert not [ln for ln in scoped if re.search(r" (while|sort)\(", ln)]
+
+
 def _hlo_without_metadata(lowered) -> str:
     """The compiled program's computations, metadata left out."""
     text = lowered.compile().as_text()
