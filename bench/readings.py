@@ -1,5 +1,7 @@
-"""Readings that set the limits of ``correct``: the numbers compared,
-for the program and for the control, over several seeds, in one
+"""Readings that set the limits of ``correct``: the numbers compared
+(``check.py``; which of them a cell has follows from what its
+configuration's reference gives), for the program and for the control
+(that reference one precision lower), over several seeds, in one
 process.
 
     python bench/readings.py --config ccn-upgrade-fp --traffic backlog \
@@ -7,7 +9,8 @@ process.
 
 Each seed is one short run of the cell at its own load (``run.py``
 with the control's readings added on the same sample); a configuration
-need not be in ``BENCHMARK.json``. One JSON line per seed.
+need not be in ``BENCHMARK.json``, and may be of any model with a
+reference in ``references/``. One JSON line per seed.
 """
 from __future__ import annotations
 

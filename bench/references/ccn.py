@@ -15,6 +15,12 @@ out so that any backend computes it). It also gives, per event, how
 near the k-nearest selection came to a tie (``knn_margin``): the
 smallest gap, over real hits and blocks, between the k-th and the
 next distance, as a share of the event's largest squared coordinate.
+
+The harness calls it through ``pool``, ``weights``, ``expected``,
+``served`` and ``model_flops``, the functions every reference module
+provides (``bench/run.py``, ``bench/check.py``): the events come from
+the benchmark's copy of the generator (``bench/events.py``), the
+operation count from ``bench/flops.py``.
 """
 from __future__ import annotations
 
@@ -24,11 +30,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import events, flops
+
 BIG = 1e30
+HEADS = ("beta", "coords", "energy", "cls")
 
 
 def head_dims(w):
-    return {"beta": 1, "coords": 2, "energy": 1, "cls": w["n_classes"]}
+    return dict(zip(HEADS, (1, 2, 1, w["n_classes"])))
 
 
 def layers(w):
@@ -68,9 +77,7 @@ def place_threshold(p, hits, mask, w):
     triggers exactly the events whose most energetic selected point
     exceeds ``e_trigger - b``."""
     _, sel = forward(p, hits, mask, w)
-    km = w["k_max"]
-    valid = np.arange(km)[None, :] < sel["n_clusters"][:, None]
-    top = np.where(valid, sel["cluster_e"], -np.inf).max(axis=1)
+    top = _top_energy(sel)
     b = w["e_trigger"] - float(np.median(top[np.isfinite(top)]))
     p = dict(p)
     p["head_energy"] = {"w": p["head_energy"]["w"],
@@ -205,3 +212,52 @@ def forward(p, hits, mask, w, *, control=False, block=256):
             p, jnp.asarray(hits[i:i + block]), jnp.asarray(mask[i:i + block]),
             _key(w), control)))
     return jax.tree_util.tree_map(lambda *xs: np.concatenate(xs), *outs)
+
+
+def _top_energy(sel):
+    """Per event, the energy of its most energetic selected point
+    (-inf where none was selected)."""
+    km = sel["cluster_e"].shape[1]
+    valid = np.arange(km)[None, :] < np.asarray(sel["n_clusters"])[:, None]
+    return np.where(valid, sel["cluster_e"], -np.inf).max(axis=1)
+
+
+# ------------------------------------------------------ the harness's seam ----
+def pool(det, n, seed):
+    """``n`` events of the detector ``det`` from ``seed``: the feeds
+    (``hits``, ``mask``), each event's real hit count, and the arrays
+    the comparison reads (the same hits and masks)."""
+    ev = events.generate(det, n, seed)
+    return {"feeds": ev, "rows": ev["mask"].sum(1).astype(np.int64),
+            "data": ev}
+
+
+def weights(seed, w, gain, calib):
+    """The weights of ``init_params``, with the energy bias placed on
+    the calibration events ``calib`` (a ``pool``)."""
+    return place_threshold(init_params(seed, w, gain), calib["data"]["hits"],
+                           calib["data"]["mask"], w)
+
+
+def expected(p, data, w, *, control=False):
+    """What the served trigger should give on the events ``data``: the
+    heads, the hit mask, the trigger decision with its margin (the most
+    energetic selected point's energy minus ``e_trigger``) and the
+    events' k-NN tie margins; numpy out."""
+    heads, sel = forward(p, data["hits"], data["mask"], w, control=control)
+    return {"heads": heads, "mask": data["mask"],
+            "decision": np.asarray(sel["trigger"], bool),
+            "decision_margin": _top_energy(sel) - w["e_trigger"],
+            "tie_margin": np.asarray(sel["knn_margin"])}
+
+
+def served(out):
+    """The heads and the trigger decision of one served event's
+    outputs."""
+    return {"heads": {h: out[h] for h in HEADS},
+            "decision": bool(np.asarray(out["cps"]["trigger"]))}
+
+
+def model_flops(rows, w):
+    """Operations of one event with ``rows`` real hits."""
+    return flops.model_flops(rows, w)

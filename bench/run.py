@@ -5,25 +5,30 @@
 
 A cell is a workload of ``BENCHMARK.json``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
-(``bench/traffic/<traffic>.json``). The run builds the served trigger
-as ``python -m repro.launch.serve`` builds it (``sut.py``), with
-weights, calibration events and an event pool made from ``--seed``,
-warms up every launch shape through the service, and then offers the
-mix for ``--seconds``: ``backlog`` keeps the service's bounded queue
-full; ``poisson`` submits open-loop at the mix's fixed rate. Every
-latency is timed from an event's scheduled arrival to the resolution
-of its future, which comes after the in-order release stage.
+(``bench/traffic/<traffic>.json``). Everything that belongs to one
+model comes from the plain reference the configuration names
+(``bench/references/<reference>.py``): its ``pool`` makes the event
+pool and the calibration events from ``--seed`` and the detector's
+knobs, its ``weights`` the weights, its ``model_flops`` the operations
+of one event. The run builds the served model as ``python -m
+repro.launch.serve`` builds it (``sut.py``, through the deployment
+that ``serve.py`` gives the model named by the configuration's
+``--model``), warms up every launch shape through the service, and
+then offers the mix for ``--seconds``: ``backlog`` keeps the service's
+bounded queue full; ``poisson`` submits open-loop at the mix's fixed
+rate. Every latency is timed from an event's scheduled arrival to the
+resolution of its future, which comes after the in-order release
+stage.
 
 After the window every event submitted in it is awaited, the
 service is closed, and a sample of the released events, drawn from
-the seed, is compared with the plain reference named by the
-configuration (``references/``): see ``check.py``. With ``--trace 0``
-the result carries the cell's end-to-end metrics; with ``--trace 1``
-the window runs under the profiler and the result carries its
-per-layer metrics (``metrics/<name>.py``) and a breakdown of device
-time and idle gaps. The last line of standard output is the result
-as one JSON object; the numbers compared and their limits are also
-the last lines of standard error.
+the seed, is compared with the reference: see ``check.py``. With
+``--trace 0`` the result carries the cell's end-to-end metrics; with
+``--trace 1`` the window runs under the profiler and the result
+carries its per-layer metrics (``metrics/<name>.py``) and a breakdown
+of device time and idle gaps. The last line of standard output is the
+result as one JSON object; the numbers compared and their limits are
+also the last lines of standard error.
 
 The run needs a TPU with as many chips as the cell asks for; with
 none, or without the repository's sources beside ``bench/``, it exits
@@ -56,7 +61,7 @@ ROOT = BENCH.parent
 if sys.path and pathlib.Path(sys.path[0] or ".").resolve() == BENCH:
     sys.path[0] = str(ROOT)
 
-from bench import check, events, flops, peaks  # noqa: E402
+from bench import check, events, peaks  # noqa: E402
 
 # submissions past which a window stops offering load (a guard on the
 # per-event arrays; far above any rate one host reaches)
@@ -100,6 +105,14 @@ def _module(path: pathlib.Path, name: str):
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
     return mod
+
+
+def reference(cfg: dict, root: pathlib.Path = ROOT):
+    """The configuration's plain reference,
+    ``bench/references/<reference>.py``."""
+    name = cfg["reference"]
+    return _module(root / "bench" / "references" / f"{name}.py",
+                   f"bench_reference_{name}")
 
 
 def reader(metric: str, root: pathlib.Path = ROOT):
@@ -310,6 +323,28 @@ def warm(svc, pool_events, microbatch: int):
     svc.submit(pool_events[0]).result(timeout=600)
 
 
+def make_inputs(cfg: dict, mix: dict, ref, seed: int, parts: dict):
+    """The event pool (with its per-event feed dicts under
+    ``"events"``), the calibration events and the weights of a run,
+    made from ``seed`` by the reference ``ref``; ``parts`` gets the
+    seconds each took."""
+    import jax
+    t = time.perf_counter()
+    det = {**cfg["detector"], **mix.get("detector", {})}
+    calib = ref.pool(det, cfg["calibration_events"], seed + 1)
+    pool = ref.pool(det, mix["pool"], seed)
+    feeds = pool["feeds"]
+    pool["events"] = [{k: v[i] for k, v in feeds.items()}
+                      for i in range(len(pool["rows"]))]
+    parts["event_pool"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    params = ref.weights(seed, cfg["widths"], cfg["weight_gain"], calib)
+    jax.block_until_ready(params)
+    parts["weights"] = time.perf_counter() - t
+    return pool, calib, params
+
+
 # ---------------------------------------------------------------- a run ----
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              root: pathlib.Path = ROOT, require_chip: bool = True,
@@ -342,25 +377,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     parts["jax_init"] = time.perf_counter() - t
 
     from bench import sut
-    ref = _module(root / "bench" / "references" / f"{cfg['reference']}.py",
-                  f"bench_reference_{cfg['reference']}")
+    ref = reference(cfg, root)
     w = cfg["widths"]
-    t = time.perf_counter()
-    det = {**cfg["detector"], **mix.get("detector", {})}
-    calib = events.generate(det, cfg["calibration_events"], seed + 1)
-    pool = events.generate(det, mix["pool"], seed)
-    pool_events = [{"hits": pool["hits"][i], "mask": pool["mask"][i]}
-                   for i in range(mix["pool"])]
-    parts["event_pool"] = time.perf_counter() - t
+    pool, calib, params = make_inputs(cfg, mix, ref, seed, parts)
+    pool_events = pool["events"]
 
     t = time.perf_counter()
-    params = ref.init_params(seed, w, cfg["weight_gain"])
-    params = ref.place_threshold(params, calib["hits"], calib["mask"], w)
-    jax.block_until_ready(params)
-    parts["weights"] = time.perf_counter() - t
-
-    t = time.perf_counter()
-    served = sut.build(cfg, params, calib, annotate=trace,
+    served = sut.build(cfg, params, calib["feeds"], annotate=trace,
                        fault=fault and functools.partial(fault, params=params))
     parts["deploy"] = time.perf_counter() - t
     svc = served.service
@@ -381,7 +404,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     cap = int(MAX_RATE_EV_S * seconds) + 1
     keep = np.random.default_rng(seed + 2).random(cap) < mix["keep_share"]
     win = Window(cap, keep)
-    win.big = int(np.argmax(pool["mask"].sum(1)))
+    win.big = int(np.argmax(pool["rows"]))
     launch = served.launch
     stats0 = _counters(svc)
     timed0 = (served.timed.calls, served.timed.seconds)
@@ -461,7 +484,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               else None)
         span_s = marked["t"] - t0
         traced = (rel >= t0) & (rel <= marked["t"])
-        hits_of = pool["mask"].sum(1)[win.pool_idx[:n][traced]]
+        rows_of = pool["rows"][win.pool_idx[:n][traced]]
         s1, tm1 = marked["stats"], marked["timed"]
         record = {
             "seconds": span_s, "chips": len(devs), "peaks": pk,
@@ -471,8 +494,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             "pipeline_calls": tm1[0] - timed0[0],
             "pipeline_seconds": tm1[1] - timed0[1],
             "queue_wait_s": queue_wait, "lag_s": lag[:marked["i"]],
-            "model_flops": float(sum(flops.model_flops(int(h), w)
-                                     for h in hits_of)),
+            "model_flops": float(sum(ref.model_flops(int(h), w)
+                                     for h in rows_of)),
             "trace": _read_trace(tdir, devs),
         }
         shutil.rmtree(tdir, ignore_errors=True)

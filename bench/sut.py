@@ -1,13 +1,16 @@
 """The system under test, built as ``python -m repro.launch.serve``
-builds it: the CCN route through ``serve.ccn_deployment`` and
-``deploy()``, behind ``ShardedTriggerService``.
+builds it: the model's graph and requirements from ``serve.py``'s
+``<model>_deployment(args, params)`` for the one model that the
+configuration's ``--model`` names (default ``ccn``), through
+``deploy()``, behind ``ShardedTriggerService``. Each pipeline is fed
+the features its graph's ``input`` ops name.
 
 A configuration's ``serve_args`` are ``serve.py`` flags and select the
 path: the per-event executable (default), ``--buckets`` (one
 batch-packed executable per occupancy bucket), or, with
 ``ragged_batch``, ``deploy(ragged=True, batch=ragged_batch)`` behind
 ``ShardedTriggerService(ragged=...)``. The benchmark supplies the
-weights and the calibration events; nothing here is measured.
+weights and the calibration feeds; nothing here is measured.
 """
 from __future__ import annotations
 
@@ -81,18 +84,32 @@ def _check_widths(cfg, graph, req):
                          f" but the program deploys {req.n_hits}")
 
 
+def deployment(args, params) -> dict:
+    """``serve.py``'s deployment of the one model ``args.model``
+    names: ``<model>_deployment(args, params)``."""
+    from repro.launch import serve
+    if len(args.model) != 1:
+        raise ValueError(f"a cell serves one model; --model names "
+                         f"{args.model}")
+    fn = getattr(serve, f"{args.model[0]}_deployment", None)
+    if fn is None:
+        raise ValueError(f"serve.py has no {args.model[0]}_deployment")
+    return fn(args, params)
+
+
 def build(cfg: dict, params, calib: dict, *, annotate: bool,
           fault=None) -> Served:
     """Deploy the configuration with ``params`` and ``calib`` (the
-    calibration events), compile its launches, and start its
-    service."""
+    calibration feeds, stacked by feature), compile its launches, and
+    start its service."""
     from repro.core.pipeline import deploy, deploy_bucketed
     from repro.launch import serve
     from repro.serving import ShardedTriggerService
     args = serve.build_parser().parse_args(cfg["serve_args"])
-    m = serve.ccn_deployment(args, params)
+    m = deployment(args, params)
     graph, req = m["graph"], m["req"]
     _check_widths(cfg, graph, req)
+    features = [op.attrs["feature"] for op in graph.inputs()]
     timed = Timed(annotate, fault)
     common = dict(n_replicas=args.replicas, window_s=2e-3,
                   policy=args.policy, loop=args.loop)
@@ -117,7 +134,7 @@ def build(cfg: dict, params, calib: dict, *, annotate: bool,
     pipe = deploy(graph, req, calibration_feeds=calib)
 
     def infer(batch):
-        return pipe({"hits": batch["hits"], "mask": batch["mask"]})
+        return pipe({k: batch[k] for k in features})
 
     jax.block_until_ready(infer({k: v[:pipe.microbatch]
                                  for k, v in calib.items()}))

@@ -25,7 +25,7 @@ BENCH = pathlib.Path(__file__).resolve().parent
 if sys.path and pathlib.Path(sys.path[0] or ".").resolve() == BENCH:
     sys.path[0] = str(BENCH.parent)
 
-from bench import events, run  # noqa: E402
+from bench import run  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -45,17 +45,10 @@ def main(argv=None) -> int:
     if devs is None:
         return 1
     from bench import sut
-    ref = run._module(b / "references" / f"{cfg['reference']}.py", "ref")
-    w = cfg["widths"]
-    det = {**cfg["detector"], **mix.get("detector", {})}
-    calib = events.generate(det, cfg["calibration_events"], args.seed + 1)
-    pool = events.generate(det, mix["pool"], args.seed)
-    pool_events = [{"hits": pool["hits"][i], "mask": pool["mask"][i]}
-                   for i in range(mix["pool"])]
-    params = ref.place_threshold(ref.init_params(args.seed, w,
-                                                 cfg["weight_gain"]),
-                                 calib["hits"], calib["mask"], w)
-    served = sut.build(cfg, params, calib, annotate=False)
+    pool, calib, params = run.make_inputs(cfg, mix, run.reference(cfg),
+                                          args.seed, {})
+    pool_events = pool["events"]
+    served = sut.build(cfg, params, calib["feeds"], annotate=False)
     svc = served.service
     run.warm(svc, pool_events, svc.microbatch)
     try:

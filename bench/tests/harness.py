@@ -1,6 +1,7 @@
 """A checkout for the harness tests: the benchmark's files plus the
-test-size configuration, traffic and metric of ``data/``, dropped
-into their directories, and a ``BENCHMARK.json`` naming them."""
+test-size configurations, traffic, metric and reference of ``data/``,
+dropped into their directories, and a ``BENCHMARK.json`` naming
+them."""
 from __future__ import annotations
 
 import json
@@ -41,11 +42,12 @@ def spec(cells):
 
 def checkout(tmp: pathlib.Path, cells) -> pathlib.Path:
     """``tmp`` as a checkout: ``bench/`` without its tests, the data
-    files dropped into ``configs/``, ``traffic/`` and ``metrics/``."""
+    files dropped into ``configs/``, ``traffic/``, ``metrics/`` and
+    ``references/``."""
     shutil.copytree(BENCH, tmp / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    for sub in ("configs", "traffic", "metrics"):
-        for f in (DATA / sub).iterdir():
+    for sub in ("configs", "traffic", "metrics", "references"):
+        for f in (DATA / sub).glob("*.*"):
             shutil.copy(f, tmp / "bench" / sub / f.name)
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec(cells)))
     return tmp
