@@ -26,7 +26,8 @@ Registered op families:
 - edge-based message passing: ``gather_edge`` (endpoint gather by an
   explicit edge list), ``edge_aggregate`` (masked segment-sum/mean of
   per-edge messages into nodes), ``eltwise`` (n-ary elementwise
-  algebra), ``batchnorm`` (masked per-event batch normalization)
+  algebra), ``batchnorm`` (inference-mode batch normalization from
+  running statistics, padded rows masked)
 """
 from __future__ import annotations
 
@@ -353,11 +354,23 @@ def _infer_eltwise(op, dims, g):
     return dims[op.inputs[0]]
 
 
+_BN_PARAMS = ("gamma", "beta", "mean", "var")
+
+
 def _infer_batchnorm(op, dims, g):
     if len(op.inputs) != 2:
         raise GraphVerificationError(
             f"{op.name}: needs (x, mask) inputs")
-    return dims[op.inputs[0]]
+    d = dims[op.inputs[0]]
+    for k in _BN_PARAMS:
+        v = (op.params or {}).get(k)
+        if v is None or tuple(v.shape) != (d,):
+            raise GraphVerificationError(
+                f"{op.name}: batchnorm needs a ({d},) {k!r} param, got "
+                f"{None if v is None else tuple(v.shape)}")
+    if "eps" not in op.attrs:
+        raise GraphVerificationError(f"{op.name}: batchnorm needs an eps")
+    return d
 
 
 # ========================================================================
@@ -445,8 +458,11 @@ def _cost_eltwise_like(op, n_hits, pb):
 
 
 def _n_edges(op, n_hits):
-    # exporters record the padded edge count; fall back to a sparse
-    # power-law-ish estimate when absent
+    # edge slots per event: exporters record the in-edges per node
+    # (E = in_edges · n_hits) or the padded edge count; fall back to a
+    # sparse power-law-ish estimate when absent
+    if op.attrs.get("in_edges"):
+        return int(op.attrs["in_edges"]) * n_hits
     return int(op.attrs.get("n_edges") or 4 * n_hits)
 
 
@@ -459,18 +475,23 @@ def _cost_gather_edge(op, n_hits, pb):
 
 
 def _cost_edge_aggregate(op, n_hits, pb):
+    # the lowered kernel (kernels/edge_aggregate.py): a (n, E) one-hot
+    # incidence slab built by compare and mask, times the (E, d)
+    # messages — n·E·d multiply-adds, not the segment sum's E·d adds
     d_out = op.out_dim or 1
     e = _n_edges(op, n_hits)
-    flops = 2.0 * e * d_out + 1.0 * n_hits * d_out
-    act = (e * d_out + n_hits * d_out) * pb
+    flops = 2.0 * n_hits * e * d_out + 2.0 * n_hits * e
+    act = (e * d_out + 2.0 * e + n_hits * d_out) * pb
     return flops, act, 0.0
 
 
 def _cost_batchnorm(op, n_hits, pb):
+    # inference mode: a per-channel affine and the row mask
     d_out = op.out_dim or 1
-    flops = 10.0 * n_hits * d_out
+    flops = 3.0 * n_hits * d_out
     act = 2.0 * n_hits * d_out * pb
-    return flops, act, 0.0
+    wb = 4.0 * d_out * pb           # gamma, beta, mean, var
+    return flops, act, wb
 
 
 def default_cost(op, n_hits, pb):

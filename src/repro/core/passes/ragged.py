@@ -25,9 +25,9 @@ its registered :class:`~repro.core.op_registry.OpSpec` generically):
   ``attrs["ragged"]`` — the executor scatters packed rows back to
   per-event layout before condensation (whose per-event math is
   unchanged);
-- ``batchnorm`` is refused: masked per-event statistics are not
-  segment-aware on the packed layout, so raggedizing one would change
-  numerics silently.
+- ``batchnorm`` is refused: it normalises the node or edge rows of
+  edge-typed graphs (GatedGCN), whose edge lists have no packed
+  layout.
 
 Dense/eltwise ops are row-independent and pass through untouched —
 that row independence (plus bin packing preserving within-event column
@@ -51,9 +51,9 @@ def raggedize(g: Graph) -> Graph:
     for op in g:
         if op.op_type == "batchnorm":
             raise GraphVerificationError(
-                f"raggedize: {op.name}: batchnorm statistics are "
-                "per-event, not segment-aware — this graph cannot be "
-                "raggedized")
+                f"raggedize: {op.name}: batchnorm belongs to an "
+                "edge-typed graph, whose edge lists have no packed "
+                "layout — this graph cannot be raggedized")
 
     out = Graph()
     for nm in RAGGED_INPUTS:

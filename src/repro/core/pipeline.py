@@ -188,12 +188,18 @@ class _Executor:
         # float path (fp/bf16 or uncalibrated int8 falls back to fp)
         dt = jnp.bfloat16 if prec == "bf16" else jnp.float32
         xf = _as_fp(x, dt)
-        if xf.shape[-1] > w.shape[0]:   # lane128-padded input
-            w = jnp.pad(w, ((0, xf.shape[-1] - w.shape[0]), (0, 0)))
         kw = dict(activation=act, variant=variant,
                   bm=op.attrs_opt.get("bm", 128),
                   bn=op.attrs_opt.get("bn", 128),
                   bk=op.attrs_opt.get("bk", 512), backend=self.backend)
+        if xf.shape[-1] > w.shape[0]:   # lane128-padded input
+            if variant == "looped" and xf.shape[-1] % kw["bk"]:
+                # a K tile bound for a d_in that does not divide the
+                # lane width (70, 108) would tile past the padded
+                # columns, which the TPU compiler refuses: drop them
+                xf = xf[..., :w.shape[0]]
+            else:
+                w = jnp.pad(w, ((0, xf.shape[-1] - w.shape[0]), (0, 0)))
         wd = w.astype(dt)
         bd = None if b is None else b.astype(dt)
         if xf.ndim == 3 and variant == "looped":
@@ -347,18 +353,15 @@ class _Executor:
         raise ValueError(f"{op.name}: unknown eltwise fn {fn!r}")
 
     def _batchnorm(self, op, vals):
-        """Masked per-event batch normalization (the benchmarking-gnns
-        training-mode statistics, vectorized over the micro-batch):
-        x:(B,R,d), mask:(B,R)."""
+        """Inference-mode batch normalization: the per-channel affine
+        of the op's running statistics and γ/β, zero on the padded
+        rows. x:(B,R,d), mask:(B,R)."""
         x, mask = vals
-        d = op.out_dim
-        xf = _as_fp(x)[..., :d]
-        m = _as_fp(mask)[..., None]
-        n = jnp.maximum(m.sum(axis=1, keepdims=True), 1.0)
-        mu = (xf * m).sum(axis=1, keepdims=True) / n
-        var = (((xf - mu) ** 2) * m).sum(axis=1, keepdims=True) / n
-        eps = op.attrs.get("eps", 1e-5)
-        return (xf - mu) * jax.lax.rsqrt(var + eps) * m
+        p = op.params
+        xf = _as_fp(x)[..., :op.out_dim]
+        scale = p["gamma"] * jax.lax.rsqrt(p["var"] + op.attrs["eps"])
+        return ((xf - p["mean"]) * scale + p["beta"]) * \
+            _as_fp(mask)[..., None]
 
     def _attention(self, op, vals):
         d = op.out_dim

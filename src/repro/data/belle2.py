@@ -182,3 +182,40 @@ def event_stream_ragged(cfg: Belle2Config, batch: int, *, seed0: int = 0):
     while True:
         yield generate_ragged(cfg, batch, seed0 + step)
         step += 1
+
+
+def knn_edges(feats, mask, k: int = 8, *, block: int = 64) -> dict:
+    """k-nearest-neighbour in-edge lists of hit events, for the edge
+    models (GatedGCN): each real hit ``i`` receives an edge from each
+    of its ``k`` nearest other real hits ``j`` in (θ, φ) (features 1
+    and 2), nearest first. Squared distances are taken in float64 from
+    the float32 features, and an exact tie goes to the lower row index.
+
+    feats:(B, N, ≥3), mask:(B, N) → ``edge_index`` (B, 2, N·k) int32
+    (source, destination; hit ``i``'s edges in slots ``[i·k, i·k+k)``),
+    ``edges`` (B, N·k, 1) float32 (the pair's squared distance) and
+    ``edge_mask`` (B, N·k) float32. Slots without an edge (padded rows,
+    or fewer than ``k`` other real hits) point at row 0 with mask 0.
+    """
+    feats, mask = np.asarray(feats), np.asarray(mask)
+    b, n = mask.shape
+    out = {"edge_index": np.zeros((b, 2, n * k), np.int32),
+           "edges": np.zeros((b, n * k, 1), np.float32),
+           "edge_mask": np.zeros((b, n * k), np.float32)}
+    rows = np.arange(n)
+    for s in range(0, b, block):
+        tp = feats[s:s + block, :, 1:3].astype(np.float64)
+        real = mask[s:s + block] > 0
+        diff = tp[:, :, None, :] - tp[:, None, :, :]
+        d2 = diff[..., 0] ** 2 + diff[..., 1] ** 2           # (b, n, n)
+        d2[~real[:, None, :] | np.eye(n, dtype=bool)] = np.inf
+        nbr = np.argsort(d2, axis=-1, kind="stable")[..., :k]
+        dk = np.take_along_axis(d2, nbr, axis=-1)
+        ok = np.isfinite(dk) & real[:, :, None]               # (b, n, k)
+        m = ok.shape[0]
+        out["edge_index"][s:s + m, 0] = np.where(ok, nbr, 0).reshape(m, -1)
+        out["edge_index"][s:s + m, 1] = np.where(
+            ok, rows[None, :, None], 0).reshape(m, -1)
+        out["edges"][s:s + m, :, 0] = np.where(ok, dk, 0.0).reshape(m, -1)
+        out["edge_mask"][s:s + m] = ok.reshape(m, -1)
+    return out

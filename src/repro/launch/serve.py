@@ -224,22 +224,65 @@ def ccn_deployment(args, params=None) -> dict:
             "events": events}
 
 
-def _ccn_servable(args) -> Servable:
-    m = ccn_deployment(args)
-    return Servable("ccn", deploy(m["graph"], m["req"],
-                                  calibration_feeds=m["calibration_feeds"]),
-                    m["events"])
+def _servable(name: str, deployment: Callable) -> Callable:
+    """The function that makes a route from its ``<model>_deployment``."""
+    def build(args) -> Servable:
+        m = deployment(args)
+        return Servable(name, deploy(
+            m["graph"], m["req"], calibration_feeds=m["calibration_feeds"]),
+            m["events"])
+    return build
 
 
-def _gatedgcn_servable(args) -> Servable:
+def _hit_graphs(gen_cfg, k: int, n: int, seed: int) -> dict:
+    """``n`` generated hit events as GatedGCN feeds, stacked: the hits
+    as node features, each real hit's ``k`` nearest other real hits in
+    (θ, φ) as its in-edges, their squared distance as the edge
+    feature (``data.belle2.knn_edges``)."""
+    from repro.data.belle2 import generate, knn_edges
+    ev = generate(gen_cfg, n, seed=seed)
+    return {"nodes": ev["feats"], "node_mask": ev["mask"],
+            **knn_edges(ev["feats"], ev["mask"], k)}
+
+
+def _gatedgcn_config():
+    """GatedGCN at the benchmarking-gnns node-classification widths
+    (arXiv:2003.00982): 16 layers of 70, MLPReadout L=2; on the hits'
+    4 features, the squared distance as the one edge feature, the
+    generator's 3 classes and 8 k-NN in-edges per hit."""
     from repro.models.gnn import gatedgcn
-    cfg = gatedgcn.GatedGCNConfig(n_layers=4, d_hidden=32, d_in=8,
-                                  d_edge_in=4, n_classes=2)
-    params = gatedgcn.init(jax.random.PRNGKey(1), cfg)
-    graph = export_graph("gatedgcn", params, cfg)
-    req = _requirements(args, precision_policy="fp", n_hits=_EDGE_N)
-    return Servable("gatedgcn", deploy(graph, req),
-                    _edge_events(cfg.d_in, cfg.d_edge_in))
+    return gatedgcn.GatedGCNConfig(n_layers=16, d_hidden=70, d_in=4,
+                                   d_edge_in=1, n_classes=3,
+                                   readout_layers=2, in_edges=8)
+
+
+def gatedgcn_deployment(args, params=None) -> dict:
+    """What the GatedGCN route deploys, in the form of
+    ``ccn_deployment``: GatedGCN at its published widths (16 layers of
+    70, MLP readout, inference-mode batch-norm) on the ``--detector``'s
+    hit events with k-NN edge lists built on the host. Without
+    ``params`` the weights are a random init from seed 0 with the
+    batch-norms' running statistics set on 64 calibration events, which
+    are also the calibration feeds; a caller that brings ``params``
+    brings its own calibration feeds (``calibration_feeds`` is None).
+    The route always runs float32 (``--precision`` is CCN's)."""
+    from repro.models.gnn import gatedgcn
+    _, gen_cfg = _ccn_configs(args.detector)
+    cfg = _gatedgcn_config()
+    calib = None
+    if params is None:
+        calib = _hit_graphs(gen_cfg, cfg.in_edges, 64, seed=123)
+        params = gatedgcn.set_running_stats(
+            gatedgcn.init(jax.random.PRNGKey(0), cfg), calib, cfg)
+
+    def events(n, seed):
+        g = _hit_graphs(gen_cfg, cfg.in_edges, n, seed)
+        return [{k: v[i] for k, v in g.items()} for i in range(n)]
+
+    return {"graph": export_graph("gatedgcn", params, cfg),
+            "req": _requirements(args, precision_policy="fp",
+                                 n_hits=gen_cfg.n_hits),
+            "calibration_feeds": calib, "events": events}
 
 
 def _graphsage_servable(args) -> Servable:
@@ -254,8 +297,8 @@ def _graphsage_servable(args) -> Servable:
 
 
 MODELS: dict[str, Callable] = {
-    "ccn": _ccn_servable,
-    "gatedgcn": _gatedgcn_servable,
+    "ccn": _servable("ccn", ccn_deployment),
+    "gatedgcn": _servable("gatedgcn", gatedgcn_deployment),
     "graphsage": _graphsage_servable,
 }
 

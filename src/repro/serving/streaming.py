@@ -164,6 +164,10 @@ class StreamingReplicaEngine(ReplicaEngine):
                         continue
                     staged.append((s, t, now, ev, f))
                 st.set(events=len(staged), queued=self._q.qsize())
+                if spans._tracing() and "edge_mask" in staged[0][3]:
+                    # an edge model's real edges, counted on the host
+                    st.set(edges=sum(int(np.count_nonzero(
+                        it[3]["edge_mask"])) for it in staged))
                 try:
                     self._launch(staged)
                 except Exception:  # noqa: BLE001 — a malformed event

@@ -30,7 +30,8 @@ _tracing = TraceAnnotation.is_enabled
 SLOT_WAIT = "trigger.slot_wait"
 # launcher (deadline loop: dispatch worker): queue sweep, copy into the
 # input ring, device_put; stats events (launch width), queued (queue
-# depth after the sweep)
+# depth after the sweep), and in the streaming loop for an edge model
+# (an event feed named edge_mask) edges, the real edges staged
 PACK = "trigger.pack"
 # dispatch worker: the call into the served pipeline; stat events
 PIPELINE = "trigger.pipeline"

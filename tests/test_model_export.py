@@ -59,6 +59,15 @@ def _event(feeds, b):
     return {k: v[b] for k, v in feeds.items()}
 
 
+def _ggcn_params(seed):
+    """GatedGCN weights with its batch-norms' running statistics set on
+    calibration graphs, so that the served inference-mode BN is not
+    near the identity."""
+    calib = _edge_feeds(GGCN_CFG.d_in, GGCN_CFG.d_edge_in, seed=100 + seed)
+    return gatedgcn.set_running_stats(
+        gatedgcn.init(jax.random.PRNGKey(seed), GGCN_CFG), calib, GGCN_CFG)
+
+
 # ------------------------------------------------------------- registry ----
 def test_exporter_registry_lists_models():
     names = exporters()
@@ -110,7 +119,9 @@ def test_gatedgcn_export_rejects_graph_readout():
 # ------------------------------------------------- deployed vs eager ----
 @pytest.mark.parametrize("backend", backend_sweep())
 def test_gatedgcn_deploy_matches_eager(backend):
-    params = gatedgcn.init(jax.random.PRNGKey(1), GGCN_CFG)
+    """The served graph's inference-mode batch-norms agree with the
+    eager ``apply`` (running statistics, the default mode)."""
+    params = _ggcn_params(1)
     g = export_graph("gatedgcn", params, GGCN_CFG)
     pipe = deploy(g, _req(), kernel_backend=backend)
     feeds = _edge_feeds(GGCN_CFG.d_in, GGCN_CFG.d_edge_in)
@@ -139,7 +150,7 @@ def test_graphsage_deploy_matches_eager(backend):
 def test_gatedgcn_deploy_batched_executable():
     """The batch-packed executable (one whole-batch launch per segment)
     agrees with the per-event-shaped one."""
-    params = gatedgcn.init(jax.random.PRNGKey(1), GGCN_CFG)
+    params = _ggcn_params(1)
     g = export_graph("gatedgcn", params, GGCN_CFG)
     feeds = _edge_feeds(GGCN_CFG.d_in, GGCN_CFG.d_edge_in, seed=9)
     lo = deploy(export_graph("gatedgcn", params, GGCN_CFG), _req(),
@@ -150,8 +161,9 @@ def test_gatedgcn_deploy_batched_executable():
 
 def test_gatedgcn_deploy_all_design_points():
     """Every design point lowers the edge-typed ops (partition, fuse,
-    parallelize, kernel_opt all see them) and agrees with eager."""
-    params = gatedgcn.init(jax.random.PRNGKey(3), GGCN_CFG)
+    parallelize, kernel_opt all see them) and agrees with the eager
+    inference-mode ``apply``."""
+    params = _ggcn_params(3)
     feeds = _edge_feeds(GGCN_CFG.d_in, GGCN_CFG.d_edge_in, seed=6)
     want = gatedgcn.apply(params, _event(feeds, 0), GGCN_CFG)
     for dp in (1, 2, 3):

@@ -181,6 +181,31 @@ def test_pipeline_spans(tmp_path):
     assert names.count(spans.RAGGED_UNPACK) >= launches
 
 
+def test_edge_model_pack_counts_its_edges(tmp_path):
+    """The streaming launcher's pack stage of an edge model (event feeds
+    that hold an ``edge_mask``) also states the real edges it stages,
+    counted on the host."""
+    rng = np.random.default_rng(0)
+    evs = [{"x": np.float32(i),
+            "edge_mask": (rng.random(16) < 0.5).astype(np.float32)}
+           for i in range(24)]
+    svc = ShardedTriggerService(_infer, microbatch=4, devices=None,
+                                loop="streaming")
+
+    def serve():
+        futs = [svc.submit(ev) for ev in evs]
+        for f in futs:
+            f.result(timeout=30)
+        svc.drain()
+
+    got = _profiled(tmp_path, serve)
+    svc.close()
+    packs = [st for n, _, st in got if n == spans.PACK]
+    assert sum(st["events"] for st in packs) == len(evs)
+    assert sum(st["edges"] for st in packs) == \
+        sum(int(ev["edge_mask"].sum()) for ev in evs)
+
+
 def test_graph_ops_lower_under_their_scopes():
     """Each graph op's name reaches its device operations' op_name."""
     g, req, deploy, feeds = _ccn()

@@ -270,25 +270,42 @@ def test_deployed_ragged_ccn_pipeline_compiles(spec):
         "segids": spec((B, N), i32), "slots": spec((B, N), i32)}))
 
 
-def test_deployed_gatedgcn_pipeline_compiles(spec):
-    """serve.py's GatedGCN route. Its P-search on the chip picks
-    different P for the MXU and XLA segments, so the launch also
-    exercises the chunked segment path."""
+@pytest.fixture(scope="module")
+def gatedgcn_program(spec):
+    """The compiled text of serve.py's GatedGCN launch at the published
+    widths (16 layers of 70, d_in 4, d_edge_in 1, 3 classes) on
+    128-node events with 8 in-edges per node (1,024 edge slots), at the
+    microbatch the P-search picks for the chip."""
     from repro.models.gnn import gatedgcn
-    gcfg = gatedgcn.GatedGCNConfig(n_layers=4, d_hidden=32, d_in=8,
-                                   d_edge_in=4, n_classes=2)
+    gcfg = gatedgcn.GatedGCNConfig(n_layers=16, d_hidden=70, d_in=4,
+                                   d_edge_in=1, n_classes=3)
     g = export_graph("gatedgcn",
                      gatedgcn.init(jax.random.PRNGKey(1), gcfg), gcfg)
-    n, e = 64, 256
-    pipe = deploy(g, dataclasses.replace(_tpu_req(precision_policy="fp"),
-                                         n_hits=n),
+    n, e = N, N * gcfg.in_edges
+    pipe = deploy(g, _tpu_req(precision_policy="fp"),
                   kernel_backend="pallas")
     mb = pipe.microbatch
-    _assert_kernel(pipe.lower({
+    return pipe.lower({
         "nodes": spec((mb, n, gcfg.d_in)),
         "edge_index": spec((mb, 2, e), jnp.int32),
         "edges": spec((mb, e, gcfg.d_edge_in)),
-        "node_mask": spec((mb, n)), "edge_mask": spec((mb, e))}))
+        "node_mask": spec((mb, n)), "edge_mask": spec((mb, e))}
+        ).compile().as_text()
+
+
+def test_deployed_gatedgcn_pipeline_compiles(gatedgcn_program):
+    """serve.py's GatedGCN route: the whole 16-layer graph is one
+    program for the chip, with the edge-aggregate and dense kernels at
+    the width 70 that is not lane-aligned."""
+    assert "tpu_custom_call" in gatedgcn_program
+
+
+def test_deployed_gatedgcn_scopes(gatedgcn_program):
+    """Its graph ops' names reach the device operations' ``op_name``:
+    the last layer's aggregate, the first layer's edge batch-norm and
+    the readout MLP."""
+    for scope in ("l15_agg/", "l0_ebn/", "readout"):
+        assert f'op_name="jit(whole)/{scope}' in gatedgcn_program, scope
 
 
 def test_deployed_cps_scope_has_no_loop_or_sort(spec):
